@@ -1,47 +1,44 @@
-"""Restart cold-start measurement: second process, warm compile cache.
+"""Restart cold-start measurement: a fresh process, warm compile cache.
 
-    python -m benchmarks.cold <backend> <cache_dir>
+    python -m benchmarks.run --quick     # writes BENCH_quick.json and
+    python -m benchmarks.cold            # fills the cache it reads here
 
-Invoked by ``benchmarks.quick`` AFTER its in-process run populated
-``cache_dir`` (DESIGN.md §14). This process rebuilds the IDENTICAL
-index + workloads (same seeds/sizes via ``quick.build_workloads``),
-points a fresh Executor at the warm cache, and follows the supported
-restart path: replay the recorded prewarm manifest at startup — every
-sticky tier and (program, signature) loads from the warm disk cache
-OFF the serving path, timed separately as ``prewarm_ms`` — then time
-the FIRST dispatch of every spec, i.e. the latency a request actually
-sees after a restart (it used to pay the full trace+compile bill
-inline). The acceptance bar (tools/check.sh): warm-restart cold must
-be >= 5x better than the same run's empty-cache ``cold_us_per_q``.
-
-Emits one JSON line on stdout:
-
-    {"specs": {name: cold_warm_us_per_q, ...},
-     "prewarm_ms": P, "prewarm_compiled": C,
-     "disk_cache_hits": H, "disk_cache_misses": M}
+Run as its own top-level command AFTER the quick bench has exited, never
+as a child of a process that has used JAX (on a TPU that parent would
+hold the chip). For every backend in ``BENCH_quick.json`` this process
+rebuilds the IDENTICAL index + workloads (same seeds/sizes via
+``quick.build_workloads``), points a fresh Executor at the warm cache
+(``quick.cache_root()``), and follows the supported restart path:
+replay the recorded prewarm manifest at startup — every sticky tier and
+(program, signature) loads from the warm disk cache OFF the serving
+path, timed separately as ``prewarm_ms`` — then time the FIRST dispatch
+of every spec, i.e. the latency a request actually sees after a
+restart. The results are merged into ``BENCH_quick.json``
+(``cold_warm_us_per_q`` per spec, ``cold_restart`` per backend). Any
+failure — a missing manifest included — raises and exits non-zero.
 """
 from __future__ import annotations
 
 import json
 import os
-import sys
 import time
 
-import jax
 
-
-def main(backend: str, cache_dir: str) -> None:
-    from benchmarks.quick import build_workloads
+def probe(w: dict, backend: str, cache_dir: str) -> dict:
+    import jax
     from repro.core import EngineConfig, Executor
     from repro.core.compile_cache import load_manifest
 
-    w = build_workloads()
     ex = Executor(w["index"], config=EngineConfig(
         backend=backend, compile_cache_dir=cache_dir))
-    man = load_manifest(os.path.join(
-        cache_dir, f"prewarm_quick_{backend}.json"))
+    man_path = os.path.join(cache_dir, f"prewarm_quick_{backend}.json")
+    man = load_manifest(man_path)
+    if man is None:
+        raise FileNotFoundError(
+            f"no prewarm manifest at {man_path}: run "
+            "`python -m benchmarks.run --quick` first")
     t0 = time.perf_counter()
-    pre = ex.prewarm(man, exercise=True) if man else {"compiled": 0}
+    pre = ex.prewarm(man, exercise=True)
     prewarm_ms = (time.perf_counter() - t0) * 1e3
     specs = {}
     for name, spec, args, denom in w["workload"]:
@@ -49,12 +46,37 @@ def main(backend: str, cache_dir: str) -> None:
         jax.block_until_ready(ex.run(spec, *args))
         specs[name] = round((time.perf_counter() - t0) * 1e6 / denom, 2)
     st = ex.stats()
-    print(json.dumps({"specs": specs,
-                      "prewarm_ms": round(prewarm_ms, 1),
-                      "prewarm_compiled": pre.get("compiled", 0),
-                      "disk_cache_hits": st["disk_cache_hits"],
-                      "disk_cache_misses": st["disk_cache_misses"]}))
+    return {"specs": specs,
+            "prewarm_ms": round(prewarm_ms, 1),
+            "prewarm_compiled": pre.get("compiled", 0),
+            "disk_cache_hits": st["disk_cache_hits"],
+            "disk_cache_misses": st["disk_cache_misses"]}
+
+
+def main() -> None:
+    out = os.environ.get("BENCH_QUICK_OUT", "BENCH_quick.json")
+    with open(out) as f:
+        rep = json.load(f)
+    # the quick bench's sizes, set before benchmarks.common is imported
+    os.environ["BENCH_N"] = str(rep["bench_n"])
+    os.environ["BENCH_Q"] = str(rep["bench_q"])
+    from benchmarks.common import emit
+    from benchmarks.quick import build_workloads, cache_root
+
+    w = build_workloads()
+    root = cache_root()
+    for backend, br in rep["backends"].items():
+        cold = probe(w, backend, root)
+        for name, us in cold["specs"].items():
+            br["specs"][name]["cold_warm_us_per_q"] = us
+            emit(f"quick/{backend}/{name}/cold_warm", us)
+        br["cold_restart"] = {k: v for k, v in cold.items()
+                              if k != "specs"}
+    rep["specs"] = rep["backends"][rep["backend_default"]]["specs"]
+    with open(out, "w") as f:
+        json.dump(rep, f, indent=2, sort_keys=True)
+    print(f"# merged the restart probe into {out}")
 
 
 if __name__ == "__main__":
-    main(sys.argv[1], sys.argv[2])
+    main()

@@ -12,9 +12,7 @@ from __future__ import annotations
 
 import json
 import os
-import subprocess
-import sys
-import tempfile
+import shutil
 import time
 
 import jax
@@ -28,11 +26,18 @@ from repro.core import (CircleQuery, DeleteBatch, EngineConfig, Executor,
 from repro.data import spatial as ds
 
 OUT = os.environ.get("BENCH_QUICK_OUT", "BENCH_quick.json")
-# persistent compiled-executable cache for the bench executors
-# (DESIGN.md §14). Default: a FRESH temp dir per run, so the in-process
-# cold_us_per_q really measures the empty-cache cold path and the
-# benchmarks.cold subprocess measures the warm-cache restart against it.
-CACHE_DIR = os.environ.get("BENCH_CACHE_DIR")
+
+
+def cache_root() -> str:
+    """Persistent compiled-executable cache of the bench executors
+    (DESIGN.md §14): ``BENCH_CACHE_DIR``, else the fixed ``bench_quick``
+    subdirectory of the program's cache root, which ``main`` empties
+    first so the in-process cold_us_per_q measures the empty-cache cold
+    path; ``python -m benchmarks.cold``, run after this module exits,
+    measures the warm-cache restart against it."""
+    from repro.core.compile_cache import default_cache_root
+    return (os.environ.get("BENCH_CACHE_DIR")
+            or os.path.join(default_cache_root(), "bench_quick"))
 
 
 def build_workloads():
@@ -229,33 +234,13 @@ def bench_updates(index, x, y, backend: str, workload) -> dict:
     return out
 
 
-def run_cold(backend: str, cache_dir: str) -> dict:
-    """Run ``benchmarks.cold`` in a SECOND process against the (now
-    warm) compile cache dir: a true restart — fresh jax runtime, fresh
-    executor — timing first-dispatch cold per spec. Returns its JSON
-    report ({} on failure: the cold column is additive, never fatal)."""
-    env = dict(os.environ, PYTHONPATH="src", BENCH_N=str(BENCH_N),
-               BENCH_Q=str(BENCH_Q))
-    try:
-        p = subprocess.run(
-            [sys.executable, "-m", "benchmarks.cold", backend,
-             cache_dir],
-            capture_output=True, text=True, timeout=900, env=env)
-        if p.returncode != 0:
-            print(f"# cold[{backend}] failed:\n{p.stderr[-2000:]}",
-                  file=sys.stderr)
-            return {}
-        return json.loads(p.stdout.strip().splitlines()[-1])
-    except (OSError, ValueError, subprocess.TimeoutExpired) as e:
-        print(f"# cold[{backend}] failed: {e!r}", file=sys.stderr)
-        return {}
-
-
 def main():
     w = build_workloads()
     index, x, y, part = w["index"], w["x"], w["y"], w["part"]
 
-    cache_root = CACHE_DIR or tempfile.mkdtemp(prefix="bench_cache_")
+    root = cache_root()
+    if not os.environ.get("BENCH_CACHE_DIR"):
+        shutil.rmtree(root, ignore_errors=True)
     default = resolve_backend("auto").name
     order = [default] + [b for b in ("xla", "pallas") if b != default]
     report = {"bench_n": BENCH_N, "bench_q": w["q"],
@@ -266,27 +251,13 @@ def main():
     for backend in order:
         out = bench_backend(index, backend, w["workload"],
                             w["workload256"], w["workload4k"],
-                            cache_dir=cache_root)
+                            cache_dir=root)
         out["updates"] = bench_updates(index, x, y, backend,
                                        w["workload"])
         # serve column: scheduler-coalesced vs serial throughput, mixed
         # read/write latency, idle-only maintenance (benchmarks/traffic.py)
         out["serve"] = bench_serve(index, x, y, part, backend,
-                                   cache_dir=cache_root)
-        # restart cold-start: second process, warm disk cache — the
-        # acceptance bar is >= 5x better than this run's empty-cache
-        # cold_us_per_q (gated in tools/check.sh)
-        cold = run_cold(backend, cache_root)
-        for name, us in (cold.get("specs") or {}).items():
-            if name in out["specs"]:
-                out["specs"][name]["cold_warm_us_per_q"] = us
-                emit(f"quick/{backend}/{name}/cold_warm", us)
-        if cold:
-            out["cold_restart"] = {k: cold[k] for k in
-                                   ("disk_cache_hits",
-                                    "disk_cache_misses",
-                                    "prewarm_ms",
-                                    "prewarm_compiled") if k in cold}
+                                   cache_dir=root)
         report["backends"][backend] = out
     # back-compat view: the default backend is the serving configuration
     # whose trajectory the CI regression gate tracks
@@ -305,9 +276,6 @@ def main():
     with open(OUT, "w") as f:
         json.dump(report, f, indent=2, sort_keys=True)
     print(f"# wrote {OUT}")
-    if not CACHE_DIR:                    # we created the temp dir
-        import shutil
-        shutil.rmtree(cache_root, ignore_errors=True)
 
 
 if __name__ == "__main__":

@@ -243,17 +243,23 @@ class XlaBackend:
 class PallasBackend(XlaBackend):
     """Scan stages on the Pallas TPU kernels (interpret mode off-TPU).
 
-    Every full-refine scan stage has a dedicated kernel now (range,
-    fused circle, point probe, kNN, join refine); only ``filter_mask``
-    remains reference-shared. ``interpret=None`` defers to
-    kernels/ops.py (interpret unless running on a real TPU).
+    Every full-refine scan stage has a dedicated kernel, and every one
+    of them compiles for the TPU (tests/test_tpu_compile.py): the
+    learned lookup (spline_search), range, fused circle, point probe,
+    kNN and join refine. Only ``filter_mask`` (the join's MBR
+    pre-filter) stays the inherited XLA stage: it feeds a mask, not a
+    count, to the join refine. ``interpret=None`` resolves once, here:
+    interpret mode unless running on a real TPU. ``self.interpret`` is
+    the flag every kernel call of this backend uses.
     """
 
     name = "pallas"
     vectorize = False     # one pallas_call per partition row (lax.map)
 
     def __init__(self, interpret: Optional[bool] = None):
-        self.interpret = interpret
+        from repro.kernels.common import interpret_default
+        self.interpret = (interpret_default() if interpret is None
+                          else bool(interpret))
 
     def lower_bound(self, part, qkf, *, radix_bits: int, probe: int):
         from repro.kernels import ops

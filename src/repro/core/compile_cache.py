@@ -8,11 +8,14 @@ dispatch to ~the steady path:
                  ``plan.cache_fingerprint`` under
                  ``<root>/entries/<fp>.bin``. A hit skips the Python
                  trace + lowering for that (program, arg-signature).
-  XLA layer      jax's own persistent compilation cache, pointed at
-                 ``<root>/xla`` with the size/time thresholds zeroed
-                 (the default thresholds skip CPU programs entirely).
-                 A hit skips the StableHLO -> machine-code compile —
-                 for both export replays AND fresh traces.
+  XLA layer      jax's own persistent compilation cache with the
+                 size/time thresholds zeroed (the default thresholds
+                 skip CPU programs entirely). It lives where
+                 ``JAX_COMPILATION_CACHE_DIR`` says when that is set —
+                 no code then sets another directory — and at
+                 ``<root>/xla`` otherwise. A hit skips the StableHLO ->
+                 machine-code compile — for both export replays AND
+                 fresh traces.
 
 Both layers are write-through and crash-safe: entries land via
 tmp-file + ``os.replace`` so readers never observe a torn write, and
@@ -31,7 +34,14 @@ Layout under ``EngineConfig.compile_cache_dir``:
                            nothing — the entries are content-addressed)
     prewarm.json           optional Executor.manifest() snapshot for
                            manifest-driven prewarm across restarts
-    xla/                   jax's persistent compilation cache
+    xla/                   jax's persistent compilation cache (unless
+                           JAX_COMPILATION_CACHE_DIR places it)
+
+``default_cache_root()`` is the root programs use when none is
+configured: the fixed ``lilis/`` subdirectory of
+``JAX_COMPILATION_CACHE_DIR`` when that is set, else ``.jax_cache/``
+at the top of the checkout (git-ignored). The path is part of what a
+later run looks up, so it never depends on the time or the process.
 
 Eviction is size-capped LRU by mtime: ``load`` bumps the entry's
 mtime, ``store`` triggers a sweep deleting oldest-first until the
@@ -39,6 +49,7 @@ entries/ tree fits ``EngineConfig.compile_cache_bytes``.
 """
 from __future__ import annotations
 
+import hashlib
 import json
 import os
 import tempfile
@@ -52,7 +63,30 @@ import jax
 from repro.core.plan import CACHE_SCHEMA, cache_fingerprint
 
 __all__ = ["CompileCache", "process_context", "enable_xla_cache",
-           "save_manifest", "load_manifest"]
+           "default_cache_root", "save_manifest", "load_manifest"]
+
+_ENV_DIR = "JAX_COMPILATION_CACHE_DIR"
+CHECKOUT_CACHE = Path(__file__).resolve().parents[3] / ".jax_cache"
+
+
+def default_cache_root() -> str:
+    """Fixed cache root: ``$JAX_COMPILATION_CACHE_DIR/lilis`` when the
+    variable is set, else ``.jax_cache/`` in the checkout."""
+    env = os.environ.get(_ENV_DIR)
+    return str(Path(env) / "lilis") if env else str(CHECKOUT_CACHE)
+
+
+def code_digest(root=Path(__file__).resolve().parents[1]) -> str:
+    """sha256 over the package's Python sources (paths and bytes). An
+    exported program is the code that traced it: without this, a cache
+    kept across a code change replays the old program (a fixed
+    mutation kernel kept returning the old, wrong answers on the
+    chip until its stale artifact stopped matching)."""
+    h = hashlib.sha256()
+    for f in sorted(Path(root).rglob("*.py")):
+        h.update(str(f.relative_to(root)).encode())
+        h.update(f.read_bytes())
+    return h.hexdigest()
 
 
 def process_context() -> dict:
@@ -63,6 +97,7 @@ def process_context() -> dict:
     dev = jax.devices()[0]
     return {
         "schema": CACHE_SCHEMA,
+        "code": code_digest(),
         "jax": jax.__version__,
         "jaxlib": jaxlib.__version__,
         "platform": dev.platform,
@@ -76,17 +111,21 @@ _XLA_DIR: Optional[str] = None
 
 
 def enable_xla_cache(path) -> bool:
-    """Point jax's persistent compilation cache at ``path`` (process-
-    global; first caller wins). Zeroes the entry-size / compile-time
-    thresholds — their defaults silently skip every CPU program."""
+    """Turn on jax's persistent compilation cache (process-global;
+    first caller wins): in ``JAX_COMPILATION_CACHE_DIR`` when that is
+    set — jax reads it itself, and ``path`` is ignored — else at
+    ``path``. Zeroes the entry-size / compile-time thresholds — their
+    defaults silently skip every CPU program."""
     global _XLA_DIR
-    path = str(path)
+    env = os.environ.get(_ENV_DIR)
+    path = str(env or path)
     with _XLA_LOCK:
         if _XLA_DIR is not None:
             return _XLA_DIR == path
         try:
             os.makedirs(path, exist_ok=True)
-            jax.config.update("jax_compilation_cache_dir", path)
+            if not env:
+                jax.config.update("jax_compilation_cache_dir", path)
             jax.config.update("jax_persistent_cache_min_entry_size_bytes",
                               -1)
             jax.config.update("jax_persistent_cache_min_compile_time_secs",

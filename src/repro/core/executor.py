@@ -45,10 +45,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-try:                                  # jax.export needs an explicit
-    from jax import export as _jax_export    # submodule import on
-except ImportError:                   # 0.4.x; None gates the disk
-    _jax_export = None                # cache off if absent entirely
+from jax import export as _jax_export
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core import keys as K
@@ -62,24 +59,6 @@ from repro.core.plan import (CircleQuery, DeleteBatch, EngineConfig,
                              exec_key)
 from repro.core import local_ops as L
 from repro.core.local_ops import _axes
-
-
-def shard_map_fn():
-    """Resolve shard_map across jax versions (jax.shard_map is new)."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is None:
-        from jax.experimental.shard_map import shard_map as sm
-    return sm
-
-
-def _shard_map_wrap(fn, mesh, in_specs, out_specs):
-    """shard_map with the replication-check kwarg spelling per version."""
-    sm = shard_map_fn()
-    kw = dict(mesh=mesh, in_specs=in_specs, out_specs=out_specs)
-    try:
-        return sm(fn, check_vma=False, **kw)
-    except (TypeError, AttributeError):  # older jax spelling
-        return sm(fn, check_rep=False, **kw)
 
 
 @dataclasses.dataclass
@@ -223,7 +202,7 @@ class Executor:
     mesh=None -> single-device; otherwise partitions are sharded over
     ``part_axis``. With ``query_axis`` set, batches of at least
     ``EngineConfig.query_shard_threshold`` queries additionally shard
-    over that mesh axis (query args padded/unpadded host-side; each
+    over that mesh axis (query args padded/unpadded in-program; each
     query-row subgroup runs the partition collectives independently).
     Local programs pull their lookup/scan stages from the kernel
     backend selected by ``EngineConfig.backend`` (core/backends.py:
@@ -295,8 +274,7 @@ class Executor:
                                      # background precompile worker
         self._disk = None            # CompileCache when configured
         self._fpc = None             # (shape_epoch, fingerprint context)
-        if (self.cfg.compile_cache_dir and mesh is None
-                and _jax_export is not None):
+        if self.cfg.compile_cache_dir and mesh is None:
             # the export layer engages on unmeshed executors (the
             # serving shape); meshed programs keep plain jit — their
             # in_shardings don't survive an abstract-args lowering
@@ -338,10 +316,12 @@ class Executor:
     def _pad_queries(self, fn):
         """Pad query args to a query-axis multiple; unpad all outputs.
 
-        Pads by repeating row 0 — a real, resolvable query — so padding
-        can never trip the adaptive ok flags that fused programs stash
-        for maintain(). Every program output leaf carries the query
-        batch as its leading axis, so unpadding is one tree_map.
+        Traced inside the jitted program. Pads by repeating row 0 — a
+        real, resolvable query — so padding can never trip the adaptive
+        ok flags that fused programs stash for maintain(). Every program
+        output leaf carries the query batch as its leading axis and
+        comes back replicated (``_compile`` gathers the query shards),
+        so unpadding is one tree_map of static slices.
         """
         qsize = self._query_shards()
 
@@ -365,9 +345,9 @@ class Executor:
         qshard=True compiles the query-axis-sharded wrapping: query
         args shard over ``query_axis`` (partitions still shard over
         ``part_axis``; collectives inside the program stay scoped to the
-        part axes, so each query-row subgroup reduces independently) and
-        outputs come back query-sharded. The host-side pad/unpad rides
-        on the compiled callable.
+        part axes, so each query-row subgroup reduces independently).
+        Each shard's outputs are gathered over the query axis, and the
+        pad/unpad is part of the same jitted program.
         """
         if key in self._cache:
             return self._cache[key]
@@ -380,19 +360,25 @@ class Executor:
             out = _Dispatch(self, key, partial(fn, axis=None), prefix=2)
         else:
             paxes = _axes(self.part_axis)
+            local = partial(fn, axis=paxes)
+            body = local
             if qshard:
                 qaxes = _axes(self.query_axis)
                 in_specs = ((P(paxes), P()) +
                             (P(qaxes),) * fn.n_query_args)
-                out_specs = P(qaxes)
+
+                def body(*a):
+                    return jax.tree_util.tree_map(
+                        lambda o: jax.lax.all_gather(o, qaxes, axis=0,
+                                                     tiled=True),
+                        local(*a))
             else:
                 in_specs = (P(paxes),) + (P(),) * (fn.n_query_args + 1)
-                out_specs = P()
-            wrapped = _shard_map_wrap(partial(fn, axis=paxes), self.mesh,
-                                      in_specs, out_specs)
-            out = jax.jit(wrapped)
-            if qshard:
-                out = self._pad_queries(out)
+            wrapped = jax.shard_map(body, mesh=self.mesh,
+                                    in_specs=in_specs, out_specs=P(),
+                                    check_vma=False)
+            out = jax.jit(self._pad_queries(wrapped) if qshard
+                          else wrapped)
         self._cache[key] = out
         return out
 
@@ -423,6 +409,41 @@ class Executor:
     def _call(self, fn, *args):
         self.dispatches += 1
         return fn(self.parts, self.bounds, *args)
+
+    def _call_rows(self, fn, args, cw: int):
+        """Dispatch ``args`` through ``fn`` in ``cw``-row slices and
+        concatenate the outputs. Per-row outputs are pure f(row, tier),
+        so the concat is bitwise the single-dispatch result. A short
+        tail slice pads with its own row 0 (a real query) up to ``cw``
+        and un-pads, keeping the executable count at one."""
+        width = args[0].shape[0]
+        if cw >= width:
+            return self._call(fn, *args)
+        outs = []
+        for s in range(0, width, cw):
+            cargs = tuple(a[s:s + cw] for a in args)
+            tail = cw - cargs[0].shape[0]
+            if tail > 0:
+                cargs = tuple(jnp.concatenate(
+                    [a, jnp.repeat(a[:1], tail, axis=0)], axis=0)
+                    for a in cargs)
+            out = self._call(fn, *cargs)
+            if tail > 0:
+                out = jax.tree_util.tree_map(lambda a: a[:-tail], out)
+            outs.append(out)
+        return jax.tree_util.tree_map(
+            lambda *leaves: jnp.concatenate(leaves, axis=0), *outs)
+
+    def _mem_rows(self, tier) -> int:
+        """Max rows per dispatch at ``tier`` so the windowed candidate
+        plane — rows x cand x 4 subintervals x cap elements — stays
+        within ``scan_chunk_elems``. Without it a wide batch of large
+        queries asks for more device memory than the chip has (on a
+        10^7-point index a 256-row RangeQuery batch at tier (4096, 64)
+        wanted 15.25 GB of a v5e's 16 GB). Power of two, at least 1."""
+        per_row = 4 * max(1, int(tier[0])) * max(1, int(tier[1]))
+        rows = max(1, self.cfg.scan_chunk_elems // per_row)
+        return 1 << (rows.bit_length() - 1)
 
     def _all_ok(self, ok) -> bool:
         """The ONLY host-blocking read on the QUERY path (counted)."""
@@ -524,6 +545,9 @@ class Executor:
                     self._disk.hits if self._disk else 0,
                 "disk_cache_misses":
                     self._disk.misses if self._disk else 0,
+                "disk_cache_uncacheable": sum(
+                    1 for d in self._cache.values()
+                    if isinstance(d, _Dispatch) and d._uncacheable),
                 "async_compiles": self.async_compiles,
                 "sticky": dict(self._sticky),
                 "epoch": self.index.epoch,
@@ -531,6 +555,15 @@ class Executor:
                 "updates": self.updates,
                 "refits": self.refits,
                 "pending_refit": sorted(self._refit_pending)}
+
+    def compiled_programs(self):
+        """(exec_key, args signature, compiled executable) of every
+        program this unmeshed executor has realized (meshed executors
+        cache plain jitted callables, which have none to list)."""
+        for key, d in list(self._cache.items()):
+            if isinstance(d, _Dispatch):
+                for sig, compiled in list(d._fns.items()):
+                    yield key, sig, compiled
 
     @property
     def epoch(self) -> int:
@@ -1320,7 +1353,6 @@ class Executor:
         self._escalators[op.base] = op.escalate
         self._demoters[op.base] = op.demote
         sticky = self._sticky.get(op.base)
-        qs = self._use_qshard(pargs[0].shape[0])
         if (sticky is not None and not strict and op.fused is not None
                 and start is None):
             if (self.cfg.tier_buckets and op.probe is not None
@@ -1331,18 +1363,19 @@ class Executor:
             # steady state: fused windowed+fallback program, no host
             # sync; the ok flags are stashed (not read) so maintain()
             # can re-tune the sticky tier off the hot path
-            fn = self._compile(self._key(op.base, "fused", sticky,
-                                         qshard=qs),
-                               lambda: op.fused(*sticky), qshard=qs)
-            out, ok = self._call(fn, *pargs)
+            out, ok = self._fused_chunked(op, sticky, pargs,
+                                          pargs[0].shape[0])
             self._pending[op.base] = (sticky, ok)
             return op.post(out)
         cap, cand = start or sticky or op.initial
+        qn = pargs[0].shape[0]
         while True:
+            cw = min(qn, self._mem_rows((cap, cand)))
+            qs = self._use_qshard(cw)
             fn = self._compile(self._key(op.base, "w", (cap, cand),
                                          qshard=qs),
                                lambda: op.window(cap, cand), qshard=qs)
-            res = self._call(fn, *pargs)
+            res = self._call_rows(fn, pargs, cw)
             hit = self._all_ok(op.get_ok(res))
             maxed = op.maxed(cap, cand)
             if hit or (maxed and op.sticky_on_maxed):
@@ -1466,42 +1499,22 @@ class Executor:
         the budget wide batches go memory-bound and per-query cost
         triples. Power of two (tiles the pow2-padded buckets exactly,
         so every chunk reuses ONE executable shape) with a 256-row
-        floor so dispatch overhead stays amortized."""
+        floor so dispatch overhead stays amortized — unless the device
+        memory bound (``_mem_rows``) is lower."""
         per_row = max(1, int(tier[0]) * int(tier[1]))
         cw = max(1, self.cfg.row_chunk_elems // per_row)
         cw = max(256, 1 << (cw.bit_length() - 1))
-        return min(width, cw)
+        return min(width, cw, self._mem_rows(tier))
 
     def _fused_chunked(self, op: _AdaptiveOp, tier, bargs, width: int):
         """Dispatch ``bargs`` (width rows) through the tier's fused
-        executable in equal _row_chunk-width slices, concatenating
-        (out, ok). Per-row outputs are pure f(row, tier), so the
-        chunked concat is bitwise the single-dispatch result. A short
-        tail chunk pads with its own row 0 (a real query) up to the
-        chunk width and un-pads, keeping the executable count at one."""
+        executable in _row_chunk-width slices (``_call_rows``);
+        returns (out, ok)."""
         cw = self._row_chunk(tier, width)
         qs = self._use_qshard(cw)
         fn = self._compile(self._key(op.base, "fused", tier, qshard=qs),
                            lambda: op.fused(*tier), qshard=qs)
-        if cw >= width:
-            return self._call(fn, *bargs)
-        outs, oks = [], []
-        for s in range(0, width, cw):
-            cargs = tuple(a[s:s + cw] for a in bargs)
-            tail = cw - cargs[0].shape[0]
-            if tail > 0:
-                cargs = tuple(jnp.concatenate(
-                    [a, jnp.repeat(a[:1], tail, axis=0)], axis=0)
-                    for a in cargs)
-            out, ok = self._call(fn, *cargs)
-            if tail > 0:
-                out = jax.tree_util.tree_map(lambda a: a[:-tail], out)
-                ok = ok[:-tail]
-            outs.append(out)
-            oks.append(ok)
-        merged = jax.tree_util.tree_map(
-            lambda *leaves: jnp.concatenate(leaves, axis=0), *outs)
-        return merged, jnp.concatenate(oks, axis=0)
+        return self._call_rows(fn, bargs, cw)
 
     def _run_bucketed(self, op: _AdaptiveOp, pargs, sticky):
         """Wide-batch tier-bucketed dispatch (DESIGN.md §13).

@@ -285,9 +285,7 @@ class _LocalFn:
         mul = jnp.int32(1)
         for a in reversed(axis):
             idx = idx + jax.lax.axis_index(a) * mul
-            # psum(1) == axis size; works on jax versions without
-            # jax.lax.axis_size
-            mul = mul * jax.lax.psum(1, a)
+            mul = mul * jax.lax.axis_size(a)
         return idx * p_loc
 
 
@@ -442,7 +440,7 @@ class _RangeWindowLocal(_LocalFn):
         okq = jnp.all(ok | ~mine, axis=1)
         if axis is not None:
             vids = jax.lax.all_gather(vids, axis, axis=1, tiled=True)
-            shards = jax.lax.psum(1, axis)
+            shards = jax.lax.axis_size(axis)
             okq = jax.lax.psum(okq.astype(jnp.int32), axis) == shards
         vids, cap_ok = _keep_window(vids, cnt, self.cap)
         return cnt, vids, okq & within & cap_ok
@@ -494,7 +492,7 @@ class _CircleWindowLocal(_LocalFn):
         cnt = _psum(jnp.sum(cnts, axis=1), axis)
         okq = jnp.all(ok | ~mine, axis=1)
         if axis is not None:
-            shards = jax.lax.psum(1, axis)
+            shards = jax.lax.axis_size(axis)
             okq = jax.lax.psum(okq.astype(jnp.int32), axis) == shards
         if not self.materialize:
             return cnt, okq & within
@@ -546,7 +544,7 @@ class _CircleWindowLocal(_LocalFn):
         okq = out["ok"]
         kept = out["v"]
         if axis is not None:
-            shards = jax.lax.psum(1, axis)
+            shards = jax.lax.axis_size(axis)
             okq = jax.lax.psum(okq.astype(jnp.int32), axis) == shards
             # per-shard carries are already compacted losslessly up to
             # keep_loc >= the final bound, so gathering them shard-major
@@ -718,7 +716,7 @@ class _KnnPrunedLocal(_LocalFn):
                 bv = jnp.take_along_axis(bv_g, ix, axis=1)
                 cnt = jax.lax.psum(cnt, axis)
                 okq = jax.lax.psum(okq.astype(jnp.int32), axis) == \
-                    jax.lax.psum(1, axis)
+                    jax.lax.axis_size(axis)
             return bn, bv, okq, cnt
 
         def cond(state):
@@ -789,7 +787,7 @@ class _JoinLocal(_LocalFn):
         cnt = _psum(cnt, axis)
         okq = jnp.all(ok | ~mine, axis=1)
         if axis is not None:
-            shards = jax.lax.psum(1, axis)
+            shards = jax.lax.axis_size(axis)
             okq = jax.lax.psum(okq.astype(jnp.int32), axis) == shards
         return cnt, okq & within
 

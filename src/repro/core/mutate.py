@@ -159,6 +159,22 @@ def scatter_inserts(dkey, dx, dy, dvid, dcount, pid, key, xs, ys, vids):
             dcount.at[pid].add(1))
 
 
+def _row_hits(pids, m, n_rows: int):
+    """(n_rows, W) bool: slot s of row r is hit when some batch entry b
+    with ``pids[b] == r`` has ``m[b, s]``.
+
+    A 0/1 contraction over the batch (sums of at most ``len(pids)``
+    ones are exact in f32), not a scatter-max with repeated row ids. On
+    a TPU v5e the scatter form dropped every delta-plane update of
+    ``apply_deletes`` while the same scatter compiled alone was right;
+    the cause is unconfirmed, so both planes use this form, which
+    ``chip_smoke.py``'s delete check covers on the chip.
+    """
+    own = pids[None, :] == jnp.arange(n_rows, dtype=pids.dtype)[:, None]
+    return jnp.dot(own.astype(jnp.bfloat16), m.astype(jnp.bfloat16),
+                   preferred_element_type=jnp.float32) > 0
+
+
 def apply_deletes(xp, yp, vidp, count, dxp, dyp, dvidp, dcount, dead,
                   qx, qy, pid1, pid2):
     """Tombstone every live copy of each (x, y) in its two candidate
@@ -178,8 +194,7 @@ def apply_deletes(xp, yp, vidp, count, dxp, dyp, dvidp, dcount, dead,
     rows_v = vidp[pids]
     m = ((rows_x == qx2[:, None]) & (rows_y == qy2[:, None]) &
          (rows_v >= 0) & (posn[None, :] < count[pids][:, None]))
-    hit = jnp.zeros(xp.shape, jnp.int32).at[pids].max(
-        m.astype(jnp.int32)) > 0
+    hit = _row_hits(pids, m, xp.shape[0])
     newly = hit & (vidp >= 0)
     new_x = jnp.where(hit, PAD_COORD, xp)
     new_y = jnp.where(hit, PAD_COORD, yp)
@@ -195,8 +210,7 @@ def apply_deletes(xp, yp, vidp, count, dxp, dyp, dvidp, dcount, dead,
         drv = dvidp[pids]
         dm = ((drx == qx2[:, None]) & (dry == qy2[:, None]) &
               (drv >= 0) & (slot[None, :] < dcount[pids][:, None]))
-        dhit = jnp.zeros(dxp.shape, jnp.int32).at[pids].max(
-            dm.astype(jnp.int32)) > 0
+        dhit = _row_hits(pids, dm, dxp.shape[0])
         dnew = dhit & (dvidp >= 0)
         dxp = jnp.where(dhit, PAD_COORD, dxp)
         dyp = jnp.where(dhit, PAD_COORD, dyp)

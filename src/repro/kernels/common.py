@@ -3,7 +3,6 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 
 def interpret_default() -> bool:
@@ -25,18 +24,5 @@ def pad_to(a, n: int, axis: int, fill):
     return jnp.pad(a, widths, constant_values=fill)
 
 
-def pad_pow2_rows(a, row: int, fill):
-    """Reshape (N,) -> (rows, row) padding with fill (for 2-D TPU blocks)."""
-    n = a.shape[0]
-    rows = cdiv(n, row)
-    a = pad_to(a, rows * row, 0, fill)
-    return a.reshape(rows, row), n
-
-
 def iota2(shape, dim):
     return jax.lax.broadcasted_iota(jnp.int32, shape, dim)
-
-
-def scalars_f32(*vals):
-    """(1, len(vals)) float32 scalar carrier (SMEM-friendly)."""
-    return jnp.asarray([list(np.float32(v) for v in vals)], jnp.float32)

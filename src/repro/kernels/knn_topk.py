@@ -56,14 +56,16 @@ def _kernel(q_ref, cnt_ref, px_ref, py_ref, outv_ref, outi_ref, *, k: int):
     best_i = []
     for _ in range(k):                                   # static unroll
         m = jnp.max(cand_v, axis=1, keepdims=True)       # (QB, 1)
-        hit = (cand_v == m) & (jnp.cumsum(
-            (cand_v == m).astype(jnp.int32), axis=1) == 1)
+        # first lane holding the max: lowest-index tie order, as
+        # lax.top_k (the carry precedes the tile, so earlier positions
+        # win ties)
+        first = jnp.min(jnp.where(cand_v == m, lane, width), axis=1,
+                        keepdims=True)
+        hit = lane == first
         sel_i = jnp.sum(jnp.where(hit, cand_i, 0), axis=1, keepdims=True)
         best_v.append(m)
         best_i.append(sel_i)
         cand_v = jnp.where(hit, NEG, cand_v)
-        del lane
-        lane = None
     outv_ref[...] = jnp.concatenate(best_v, axis=1)
     outi_ref[...] = jnp.concatenate(best_i, axis=1)
 

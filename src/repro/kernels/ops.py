@@ -43,12 +43,13 @@ def spline_search(queries, knot_keys, knot_pos, radix_table, keys_f,
     nq = queries.shape[0]
     qpad = cdiv(nq, _ss.QBLOCK) * _ss.QBLOCK
     q = pad_to(jnp.asarray(queries, jnp.float32), qpad, 0, 0.0)
-    scal = jnp.zeros((1, 8), jnp.float32)
-    scal = scal.at[0, 0].set(kmin).at[0, 1].set(scale)
-    scal = scal.at[0, 2].set(jnp.asarray(n_knots, jnp.float32))
-    scal = scal.at[0, 3].set(jnp.asarray(count, jnp.float32))
+    fscal = jnp.stack([jnp.asarray(kmin, jnp.float32),
+                       jnp.asarray(scale, jnp.float32)])
+    iscal = jnp.stack([jnp.asarray(n_knots, jnp.int32),
+                       jnp.asarray(count, jnp.int32)])
     pos = _ss.spline_search(q, knot_keys, knot_pos, radix_table, keys_f,
-                            scal, probe=probe, radix_bits=radix_bits,
+                            fscal, iscal, probe=probe,
+                            radix_bits=radix_bits,
                             interpret=_interp(interpret))
     return pos[:nq]
 
@@ -139,8 +140,7 @@ def point_in_polygon(poly, n_edges, x, y, interpret: Optional[bool] = None):
     npad = cdiv(n, _pip.NB) * _pip.NB
     x_p = pad_to(jnp.asarray(x, jnp.float32), npad, 0, 3e38)
     y_p = pad_to(jnp.asarray(y, jnp.float32), npad, 0, 3e38)
-    ne = jnp.zeros((1, 1), jnp.float32).at[0, 0].set(
-        jnp.asarray(n_edges, jnp.float32))
+    ne = jnp.asarray(n_edges, jnp.int32).reshape(1)
     out = _pip.point_in_polygon(jnp.asarray(poly, jnp.float32), ne,
                                 x_p, y_p, interpret=_interp(interpret))
     return out[:n]

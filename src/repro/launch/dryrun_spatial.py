@@ -25,7 +25,6 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core import keys as CK
 from repro.core.build import LearnedSpatialIndex
-from repro.core.executor import _shard_map_wrap
 from repro.core.plan import EngineConfig
 from repro.launch import hlo
 from repro.launch.mesh import make_production_mesh
@@ -114,8 +113,9 @@ def run(mesh_kind: str, out_dir: str, backend: str = "xla"):
         axes = part_axis
         in_specs = (P(axes),) + (P(),) * (local_fn.n_query_args + 1)
         from functools import partial as fpartial
-        wrapped = _shard_map_wrap(fpartial(local_fn, axis=axes), mesh,
-                                  in_specs, P())
+        wrapped = jax.shard_map(fpartial(local_fn, axis=axes),
+                                mesh=mesh, in_specs=in_specs,
+                                out_specs=P(), check_vma=False)
         t0 = time.time()
         lowered = jax.jit(wrapped, in_shardings=(
             parts_shard, rspec) + (rspec,) * len(qshapes)).lower(

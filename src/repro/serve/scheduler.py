@@ -199,6 +199,7 @@ class SpatialScheduler:
         self._q: deque = deque()
         self._cv = threading.Condition()
         self._stopping = False
+        self._error: Optional[BaseException] = None  # worker death
         self._inflight = 0        # popped but not yet resolved
         self.events: deque = deque(maxlen=4096)
         self.submitted = 0
@@ -245,15 +246,24 @@ class SpatialScheduler:
         ticket = Ticket(spec)
         req = _Request(kind, spec, args, qlen, sig, ticket)
         with self._cv:
-            if self._stopping:
-                raise RuntimeError("scheduler is closed")
+            self._check_open()
             while (self._thread is not None
                    and len(self._q) >= self.cfg.serve_queue_depth):
                 self._cv.wait(0.005)     # backpressure
+            # the worker may have died (or close() run) while we waited
+            self._check_open()
             self._q.append(req)
             self.submitted += 1
             self._cv.notify_all()
         return ticket
+
+    def _check_open(self, closing: bool = False) -> None:
+        """Raise if the worker died (or, unless ``closing``, if the
+        scheduler is closed). Call with ``_cv`` held."""
+        if self._error is not None:
+            raise RuntimeError("scheduler worker failed") from self._error
+        if self._stopping and not closing:
+            raise RuntimeError("scheduler is closed")
 
     def request_maintain(self) -> Ticket:
         """Enqueue an explicit maintenance barrier (arrival order —
@@ -262,8 +272,7 @@ class SpatialScheduler:
         compaction at a chosen moment without stopping the scheduler."""
         ticket = Ticket(None)
         with self._cv:
-            if self._stopping:
-                raise RuntimeError("scheduler is closed")
+            self._check_open()
             self._q.append(_Request("maintain", None, (), 0, None,
                                     ticket))
             self.submitted += 1
@@ -420,9 +429,9 @@ class SpatialScheduler:
     def _dispatch_reads(self, reqs):
         spec = reqs[0].spec
         total = sum(r.qlen for r in reqs)
-        width = self._pick_width(reqs, total)
-        pad = width - total
         try:
+            width = self._pick_width(reqs, total)
+            pad = width - total
             if len(reqs) == 1 and pad == 0:
                 args = reqs[0].args
             else:
@@ -493,6 +502,21 @@ class SpatialScheduler:
     # -- worker / pumping ------------------------------------------------
 
     def _worker(self):
+        try:
+            self._serve()
+        except BaseException as e:
+            # a failure outside a dispatch (which fails its own tickets)
+            # must not strand callers blocked in Ticket.result()
+            with self._cv:
+                self._error = e
+                reqs = list(self._q)
+                self._q.clear()
+                self._cv.notify_all()
+            for r in reqs:
+                r.ticket._fail(e)
+            raise
+
+    def _serve(self):
         straggler = self.cfg.serve_coalesce_us / 1e6
         while True:
             with self._cv:
@@ -518,6 +542,7 @@ class SpatialScheduler:
             deadline = time.monotonic() + timeout
             while True:
                 with self._cv:
+                    self._check_open(closing=True)
                     if not self._q and self._inflight == 0:
                         return
                     self._cv.wait(0.005)

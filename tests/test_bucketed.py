@@ -207,3 +207,25 @@ def test_row_chunked_dispatch_equals_unchunked(warmed):
     assert ex2.dispatches - d0 > 2       # probe + >1 chunk dispatch
     for a, b in zip(base, chunked):
         assert (np.asarray(a) == np.asarray(b)).all()
+
+
+@pytest.mark.parametrize("name", ["range", "circle", "join"])
+def test_memory_bounded_row_chunks_equal_unchunked(built, name):
+    """Past the candidate-plane memory budget (scan_chunk_elems) a batch
+    dispatches in row slices, on the strict escalation path and on the
+    served (bucketed, fused) path alike — and the concatenation is
+    bitwise the single-dispatch result."""
+    x, y, part, idx = built
+    _, spec, args = next(s for s in _mixed_queries(x, y, part)
+                         if s[0] == name)
+    args = tuple(a[8:24] for a in args)     # the small queries only
+    big = Executor(idx)
+    small = Executor(idx, config=EngineConfig(scan_chunk_elems=1 << 12))
+    for strict in (True, False):
+        want = big.run(spec, *args, strict=strict)
+        d0 = small.dispatches
+        got = small.run(spec, *args, strict=strict)
+        assert small.dispatches - d0 > 2      # row slices engaged
+        for a, b in zip(jax.tree_util.tree_leaves(want),
+                        jax.tree_util.tree_leaves(got)):
+            assert np.array_equal(np.asarray(a), np.asarray(b))

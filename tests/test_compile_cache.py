@@ -294,3 +294,68 @@ def test_async_precompile_is_bitwise_neutral(built):
                 "async precompilation changed a result bit"
     # the worker never runs afterwards: close() stopped it
     assert not sess.executor.precompiling
+
+
+# -- where the cache lives ---------------------------------------------
+
+def test_default_cache_root_is_fixed(monkeypatch, tmp_path):
+    """The root a program picks for itself is fixed: the ``lilis``
+    subdirectory of JAX_COMPILATION_CACHE_DIR when that is set, else
+    ``.jax_cache`` at the top of the checkout."""
+    from repro.core.compile_cache import default_cache_root
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    assert default_cache_root() == str(tmp_path / "lilis")
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    root = default_cache_root()
+    checkout = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert root == os.path.join(checkout, ".jax_cache")
+    assert root == default_cache_root()
+
+
+_ENV_CACHE = """
+import sys
+import jax
+from test_compile_cache import _build, _run_all
+from repro.core import EngineConfig, Executor
+
+x, y, part, index = _build()
+ex = Executor(index, config=EngineConfig(compile_cache_dir=sys.argv[1]))
+_run_all(ex, x, y, part)
+print(jax.config.jax_compilation_cache_dir)
+"""
+
+
+def test_env_cache_dir_is_not_overridden(tmp_path):
+    """With JAX_COMPILATION_CACHE_DIR set, jax's cache stays there: no
+    code points it elsewhere, and the export layer keeps its entries
+    under the configured root."""
+    env_dir, root = tmp_path / "jaxcache", tmp_path / "root"
+    script = tmp_path / "env_cache.py"
+    script.write_text(_ENV_CACHE)
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               JAX_COMPILATION_CACHE_DIR=str(env_dir),
+               PYTHONPATH=os.pathsep.join(
+                   [os.path.join(os.path.dirname(__file__), "..", "src"),
+                    os.path.dirname(__file__)]))
+    proc = subprocess.run([sys.executable, str(script), str(root)],
+                          env=env, capture_output=True, text=True,
+                          timeout=600)
+    assert proc.returncode == 0, proc.stderr[-2000:]
+    assert proc.stdout.strip().splitlines()[-1] == str(env_dir)
+    assert any(env_dir.iterdir()), "jax wrote nothing to its env cache"
+    assert not (root / "xla").exists()
+    assert any((root / "entries").glob("*.bin"))
+
+
+def test_code_digest_tracks_the_sources(tmp_path):
+    """The fingerprint context carries a digest of the package sources,
+    so an artifact exported by other code is never replayed."""
+    from repro.core.compile_cache import code_digest, process_context
+    pkg = tmp_path / "pkg"
+    (pkg / "core").mkdir(parents=True)
+    (pkg / "core" / "a.py").write_text("X = 1\n")
+    before = code_digest(pkg)
+    assert code_digest(pkg) == before
+    (pkg / "core" / "a.py").write_text("X = 2\n")
+    assert code_digest(pkg) != before
+    assert process_context()["code"] == code_digest()

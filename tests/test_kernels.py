@@ -49,6 +49,28 @@ def test_spline_search_kernel_sweep(part_index, p, nq):
     assert (want == truth).all()
 
 
+@pytest.mark.parametrize("p", [0, 3])
+def test_spline_search_kernel_row_ends(part_index, p):
+    """Keys below the first and past the last key of a partition put
+    the probe window at both clipped ends of the row (the kernel loads
+    it from a 128-aligned lane and masks it)."""
+    x, y, idx = part_index
+    keys_f = np.asarray(CK.keys_to_f32(idx.key[p]))
+    c = int(idx.count[p])
+    q = np.sort(np.concatenate([
+        [0.0, keys_f[0], keys_f[0] + 1, keys_f[c - 1], keys_f[c - 1] + 1,
+         float(1 << 22)],
+        keys_f[:c][np.linspace(0, c - 1, 122).astype(int)]])
+    ).astype(np.float32)
+    args = (jnp.asarray(q), idx.knot_keys[p], idx.knot_pos[p],
+            idx.radix_table[p], jnp.asarray(keys_f), idx.radix_kmin[p],
+            idx.radix_scale[p], idx.n_knots[p], idx.count[p])
+    kw = dict(probe=idx.probe, radix_bits=idx.radix_bits)
+    got = np.asarray(ops.spline_search(*args, **kw))
+    assert (got == np.asarray(ref.spline_search(*args, **kw))).all()
+    assert (got == np.searchsorted(keys_f[:c], q, side="left")).all()
+
+
 @pytest.mark.parametrize("nq", [3, 64, 200])
 def test_range_count_kernel_sweep(part_index, nq):
     x, y, idx = part_index
@@ -133,6 +155,23 @@ def test_knn_topk_kernel_sweep(part_index, k, nq):
     assert np.allclose(np.asarray(gn), np.asarray(wn), rtol=1e-6)
     for a, b in zip(np.asarray(gi), np.asarray(wi)):
         assert set(a[a >= 0]) == set(b[b >= 0])
+
+
+@pytest.mark.parametrize("k", [3, 10])
+def test_knn_topk_tie_order_is_top_k(k):
+    """Points on a coarse grid put many candidates at equal distance:
+    the kernel must break every tie by lowest position, bitwise as
+    lax.top_k does (its indices, not just its distances)."""
+    rng = np.random.default_rng(k)
+    n = 3000
+    px = (rng.integers(0, 6, n) / 8).astype(np.float32)
+    py = (rng.integers(0, 6, n) / 8).astype(np.float32)
+    qxy = jnp.asarray(np.stack([px[:40], py[:40]], 1))
+    cnt = np.int32(n - 5)
+    gn, gi = ops.knn_topk(qxy, cnt, jnp.asarray(px), jnp.asarray(py), k=k)
+    wn, wi = ref.knn_topk(qxy, cnt, jnp.asarray(px), jnp.asarray(py), k=k)
+    assert np.array_equal(np.asarray(gn), np.asarray(wn))
+    assert np.array_equal(np.asarray(gi), np.asarray(wi))
 
 
 @pytest.mark.parametrize("edges", [3, 7, 12])

@@ -16,7 +16,9 @@ caller pumps the SAME batch-forming code synchronously) pins:
 Plus a real worker-thread smoke test: concurrent submitters, all
 tickets resolve, results still match serial.
 """
+import dataclasses
 import threading
+import time
 
 import jax
 import numpy as np
@@ -233,4 +235,72 @@ def test_submit_validates_like_executor(sess):
         sched.submit("point", x[:1], y[:1])
     with pytest.raises(TypeError):
         sched.submit(PointQuery(), x[:1])      # wrong arity
+    sched.close()
+
+
+@pytest.mark.filterwarnings(
+    "ignore::pytest.PytestUnhandledThreadExceptionWarning")
+def test_failures_are_raised_not_swallowed(sess, monkeypatch):
+    """A failed dispatch raises from its ticket; a failure outside any
+    dispatch ends the worker loudly — the scheduler then refuses work
+    instead of leaving callers blocked on tickets that never resolve."""
+    x, y, part, s = sess
+    sched = s.scheduler(start=True)
+    bad = sched.submit(RangeCount(), np.zeros((2, 3), np.float32))
+    with pytest.raises(Exception):
+        bad.result(timeout=60.0)
+
+    def boom():
+        raise ValueError("maintenance probe failed")
+
+    monkeypatch.setattr(s.executor, "maintenance_due", boom)
+    ok = sched.submit(PointQuery(), x[:1], y[:1])
+    assert bool(np.asarray(ok.result(timeout=60.0))[0])
+    deadline = time.monotonic() + 60.0
+    while sched._thread.is_alive() and time.monotonic() < deadline:
+        time.sleep(0.01)
+    assert not sched._thread.is_alive()
+    with pytest.raises(RuntimeError) as err:
+        sched.submit(PointQuery(), x[:1], y[:1])
+    assert isinstance(err.value.__cause__, ValueError)
+    with pytest.raises(RuntimeError):
+        sched.drain(timeout=5.0)
+    monkeypatch.undo()
+    sched.close()
+
+
+def test_worker_death_fails_submit_blocked_on_backpressure(sess):
+    """A submit waiting on serve_queue_depth when the worker dies must
+    raise, not enqueue a request no thread will ever serve."""
+    x, y, part, s = sess
+    sched = s.scheduler(start=True)
+    sched.cfg = dataclasses.replace(sched.cfg, serve_queue_depth=1)
+    gate = threading.Event()
+
+    def stuck(straggler_wait):
+        gate.wait(60.0)          # leaves the queued request in place
+        raise ValueError("batch former failed")
+
+    sched._form_and_run = stuck
+    first = sched.submit(PointQuery(), x[:1], y[:1])   # fills the queue
+    got = {}
+
+    def blocked_submit():
+        try:
+            got["ticket"] = sched.submit(PointQuery(), x[:1], y[:1])
+        except BaseException as e:
+            got["error"] = e
+
+    t = threading.Thread(target=blocked_submit)
+    t.start()
+    time.sleep(0.3)              # let it reach the backpressure wait
+    assert t.is_alive() and sched.submitted == 1
+    gate.set()
+    t.join(60.0)
+    assert not t.is_alive()
+    assert "ticket" not in got
+    assert isinstance(got["error"], RuntimeError)
+    assert isinstance(got["error"].__cause__, ValueError)
+    with pytest.raises(ValueError):
+        first.result(timeout=5.0)
     sched.close()

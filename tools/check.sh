@@ -29,6 +29,10 @@ if git cat-file -e HEAD:BENCH_quick.json 2>/dev/null; then
   git show HEAD:BENCH_quick.json > "$BASELINE"
 fi
 python -m benchmarks.run --quick
+echo "== restart cold-start probe =="
+# its own top-level process: it must never be the child of a process
+# that has used JAX (on a TPU that parent would hold the chip)
+python -m benchmarks.cold
 
 echo "== BENCH_quick.json summary =="
 BENCH_BASELINE="$BASELINE" python - <<'EOF'
@@ -140,8 +144,7 @@ if os.environ.get("SKIP_BENCH_DIFF") != "1":
                 slow_cold.append((backend, name, s["cold_us_per_q"],
                                   cw))
     assert n_cold > 0, (
-        "no cold_warm_us_per_q columns — the benchmarks.cold "
-        "subprocess run failed (see the quick bench stderr)")
+        "no cold_warm_us_per_q columns — benchmarks.cold did not run")
     assert not slow_cold, (
         "cold-start gate: warm-cache restart cold_us_per_q is not "
         f">=5x better than empty-cache cold for {slow_cold}")
