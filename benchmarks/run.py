@@ -16,7 +16,7 @@ import time
 import traceback
 
 MODULES = ["rq1_overall", "rq2_partitioners", "rq3_datasets",
-           "rq4_selectivity", "rq4_knn_k", "rq5_build", "roofline"]
+           "rq4_selectivity", "rq4_knn_k", "rq5_build"]
 
 
 def main() -> None:

@@ -35,7 +35,9 @@ ride the zero-sync steady path.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
+import re
 import threading
 import time
 from functools import partial
@@ -50,6 +52,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core import keys as K
 from repro.core import mutate as M
+from repro.core import obs
 from repro.core import queries as Q
 from repro.core.backends import resolve_backend
 from repro.core.build import LearnedSpatialIndex
@@ -87,6 +90,33 @@ def _struct(a):
     return jax.ShapeDtypeStruct(tuple(a.shape), a.dtype)
 
 
+def program_name(key, sig=None) -> str:
+    """``lilis_<base>_<tag>[_<cap>x<cand>][_q][_w<width>]``: the name a
+    compiled program carries (as ``jit_<name>``) on a trace's ``XLA
+    Modules`` line. A pure function of the exec key (plan.exec_key; the
+    shape epoch left out) and the signature's width, the leading
+    dimension of the first query argument; update programs ("u") carry
+    their batch size in the variant instead."""
+    _bk, qshard, base, tag, variant, _epoch = key
+    parts = ["lilis", *(str(b).lower() for b in base), tag]
+    if variant:
+        parts.append("x".join(str(v) for v in variant))
+    if qshard:
+        parts.append("q")
+    if sig and sig[0][0] and tag != "u":
+        parts.append(f"w{sig[0][0][0]}")
+    return re.sub(r"[^0-9A-Za-z_]", "_", "_".join(parts))
+
+
+def _named(fn, name: str):
+    """``fn`` under ``name``, so ``jax.jit`` names its program
+    ``jit_<name>`` instead of after ``fn``."""
+    def program(*args):
+        return fn(*args)
+    program.__name__ = program.__qualname__ = name
+    return program
+
+
 class _Dispatch:
     """Per-exec_key compiling dispatcher (DESIGN.md §14).
 
@@ -103,7 +133,8 @@ class _Dispatch:
     identical program; last write wins).
     """
 
-    __slots__ = ("ex", "key", "fn", "prefix", "_fns", "_uncacheable")
+    __slots__ = ("ex", "key", "fn", "prefix", "_fns", "_names",
+                 "_uncacheable")
 
     def __init__(self, ex, key, fn, prefix: int):
         self.ex = ex
@@ -114,6 +145,7 @@ class _Dispatch:
                                   # sig — it is keyed by shape epoch);
                                   # 0: raw-args update kernels
         self._fns = {}            # args sig -> AOT-compiled executable
+        self._names = {}          # args sig -> program_name
         self._uncacheable = False
 
     @staticmethod
@@ -127,7 +159,29 @@ class _Dispatch:
 
     def _realize(self, sig, abs_args):
         ex = self.ex
+        name = program_name(self.key, sig)
+        background = threading.current_thread() is ex._pc_thread
         t0 = time.perf_counter()
+        with obs.span("lilis.exec.compile", program=name,
+                      thread="precompile" if background else "inline") \
+                as sp:
+            compiled = self._compile_sig(sig, abs_args, name, sp)
+        ms = (time.perf_counter() - t0) * 1e3
+        with ex._compile_lock:
+            if background:
+                ex.compile_ms_background += ms
+            else:
+                ex.compile_ms_inline += ms
+        self._names[sig] = name
+        self._fns[sig] = compiled
+        return compiled
+
+    def _compile_sig(self, sig, abs_args, name, sp):
+        """The executable for one signature: the export's call, or the
+        fresh program, jitted under its ``program_name``. The program
+        is exported (and stored) unnamed, so the artifacts stay as
+        they were."""
+        ex = self.ex
         compiled = None
         disk = ex._disk if not self._uncacheable else None
         fp = None
@@ -141,7 +195,7 @@ class _Dispatch:
             if data is not None:
                 try:
                     exp = _jax_export.deserialize(bytearray(data))
-                    compiled = jax.jit(exp.call) \
+                    compiled = jax.jit(_named(exp.call, name)) \
                         .lower(*abs_args).compile()
                 except Exception:
                     # corrupt / undeserializable entry: drop it and
@@ -151,14 +205,15 @@ class _Dispatch:
                         disk.hits -= 1
                         disk.misses += 1
                     compiled = None
+        sp.set(disk="off" if disk is None
+               else "hit" if compiled is not None else "miss")
         if compiled is None:
-            jitted = jax.jit(self.fn)
             if disk is not None:
                 try:
-                    exp = _jax_export.export(jitted)(*abs_args)
+                    exp = _jax_export.export(jax.jit(self.fn))(*abs_args)
                     disk.store(fp, exp.serialize(),
                                meta={"key": repr(self.key[:5])})
-                    compiled = jax.jit(exp.call) \
+                    compiled = jax.jit(_named(exp.call, name)) \
                         .lower(*abs_args).compile()
                 except Exception:
                     # program not exportable with this jax: remember
@@ -166,9 +221,8 @@ class _Dispatch:
                     self._uncacheable = True
                     compiled = None
             if compiled is None:
-                compiled = jitted.lower(*abs_args).compile()
-        ex.compile_ms_total += (time.perf_counter() - t0) * 1e3
-        self._fns[sig] = compiled
+                compiled = jax.jit(_named(self.fn, name)) \
+                    .lower(*abs_args).compile()
         return compiled
 
     def __call__(self, *args):
@@ -177,7 +231,8 @@ class _Dispatch:
         if fn is None:
             fn = self._realize(
                 sig, jax.tree_util.tree_map(_struct, args))
-        return fn(*args)
+        with obs.span("lilis.exec.launch", program=self._names[sig]):
+            return fn(*args)
 
     def warm(self, sig: Tuple) -> bool:
         """Realize (trace + compile) one signature WITHOUT executing —
@@ -267,9 +322,12 @@ class Executor:
         self.probe_syncs = 0  # wide-batch bucketing probe readbacks
         self.dispatches = 0   # compiled-program launches
         # -- compile pipeline (DESIGN.md §14) ----------------------------
-        self.compile_ms_total = 0.0  # wall-clock spent realizing
-                                     # executables (trace+lower+compile,
-                                     # or disk load+compile on a hit)
+        # wall-clock ms spent realizing executables (trace+lower+compile,
+        # or disk load+compile on a hit), on the calling (serving)
+        # thread and on the precompile thread; both under _compile_lock
+        self.compile_ms_inline = 0.0
+        self.compile_ms_background = 0.0
+        self._compile_lock = threading.Lock()
         self.async_compiles = 0      # executables realized by the
                                      # background precompile worker
         self._disk = None            # CompileCache when configured
@@ -377,8 +435,8 @@ class Executor:
             wrapped = jax.shard_map(body, mesh=self.mesh,
                                     in_specs=in_specs, out_specs=P(),
                                     check_vma=False)
-            out = jax.jit(self._pad_queries(wrapped) if qshard
-                          else wrapped)
+            out = jax.jit(_named(self._pad_queries(wrapped) if qshard
+                                 else wrapped, program_name(key)))
         self._cache[key] = out
         return out
 
@@ -408,7 +466,10 @@ class Executor:
 
     def _call(self, fn, *args):
         self.dispatches += 1
-        return fn(self.parts, self.bounds, *args)
+        if isinstance(fn, _Dispatch):    # spans its own launch
+            return fn(self.parts, self.bounds, *args)
+        with obs.span("lilis.exec.launch"):
+            return fn(self.parts, self.bounds, *args)
 
     def _call_rows(self, fn, args, cw: int):
         """Dispatch ``args`` through ``fn`` in ``cw``-row slices and
@@ -448,7 +509,8 @@ class Executor:
     def _all_ok(self, ok) -> bool:
         """The ONLY host-blocking read on the QUERY path (counted)."""
         self.host_syncs += 1
-        return bool(jnp.all(ok))
+        with obs.span("lilis.exec.sync"):
+            return bool(jnp.all(ok))
 
     def _set_sticky(self, base, variant):
         old = self._sticky.get(base)
@@ -533,6 +595,12 @@ class Executor:
         tests/tools to assert backend and query-shard compilation."""
         return list(self._cache)
 
+    @property
+    def compile_ms_total(self) -> float:
+        """Compile time on every thread: inline + background."""
+        with self._compile_lock:
+            return self.compile_ms_inline + self.compile_ms_background
+
     def stats(self) -> dict:
         return {"host_syncs": self.host_syncs,
                 "probe_syncs": self.probe_syncs,
@@ -541,6 +609,9 @@ class Executor:
                 "backend": self.backend.name,
                 "qshard_executables": sum(1 for k in self._cache if k[1]),
                 "compile_ms_total": round(self.compile_ms_total, 1),
+                "compile_ms_inline": round(self.compile_ms_inline, 1),
+                "compile_ms_background":
+                    round(self.compile_ms_background, 1),
                 "disk_cache_hits":
                     self._disk.hits if self._disk else 0,
                 "disk_cache_misses":
@@ -1103,7 +1174,7 @@ class Executor:
             if self.mesh is None:
                 self._cache[key] = _Dispatch(self, key, fn, prefix=0)
             else:
-                self._cache[key] = jax.jit(fn)
+                self._cache[key] = jax.jit(_named(fn, program_name(key)))
         self.dispatches += 1
         return self._cache[key]
 
@@ -1248,8 +1319,18 @@ class Executor:
         {sticky_key: new (cap, cand)} for the tiers that moved.
         Thread-safe (the serve scheduler runs this at queue-idle time).
         """
-        with self._lock:
+        with self._locked():
             return self._maintain_locked()
+
+    @contextlib.contextmanager
+    def _locked(self):
+        """The executor lock, its wait spanned (``lilis.exec.lock``)."""
+        with obs.span("lilis.exec.lock"):
+            self._lock.acquire()
+        try:
+            yield
+        finally:
+            self._lock.release()
 
     def _maintain_locked(self) -> dict:
         moved = {}
@@ -1307,7 +1388,7 @@ class Executor:
         if len(args) != spec.n_args:
             raise TypeError(f"{type(spec).__name__} takes {spec.n_args} "
                             f"data arguments, got {len(args)}")
-        with self._lock:
+        with self._locked():
             if isinstance(spec, InsertBatch):
                 return self._run_insert(args)
             if isinstance(spec, DeleteBatch):
@@ -1366,7 +1447,8 @@ class Executor:
             out, ok = self._fused_chunked(op, sticky, pargs,
                                           pargs[0].shape[0])
             self._pending[op.base] = (sticky, ok)
-            return op.post(out)
+            with obs.span("lilis.exec.post"):
+                return op.post(out)
         cap, cand = start or sticky or op.initial
         qn = pargs[0].shape[0]
         while True:
@@ -1538,7 +1620,9 @@ class Executor:
                   else sticky[1])
         pfn = self._compile(self._key(op.base, "p", (cand_p,)),
                             lambda: op.probe(cand_p))
-        probe = np.asarray(self._call(pfn, *pargs))
+        probe = self._call(pfn, *pargs)
+        with obs.span("lilis.exec.sync"):
+            probe = np.asarray(probe)
         self.probe_syncs += 1
         if op.bucketer is not None:
             rank = np.asarray(op.bucketer(probe, *sticky))
@@ -1561,7 +1645,8 @@ class Executor:
             # cache-residency cliff does not care about bucket count
             out, ok = self._fused_chunked(op, sticky, pargs, qn)
             self._pending[op.base] = (sticky, ok)
-            return op.post(out)
+            with obs.span("lilis.exec.post"):
+                return op.post(out)
         idxs, outs, oks = [], [], []
         for rk in present.tolist():
             sel = np.nonzero(rank == rk)[0]
@@ -1576,20 +1661,22 @@ class Executor:
                     [a, jnp.repeat(a[:1], plen - bl, axis=0)], axis=0)
                     for a in bargs)
             out, ok = self._fused_chunked(op, tier, bargs, plen)
-            if plen > bl:
-                out = jax.tree_util.tree_map(lambda a: a[:bl], out)
-                ok = ok[:bl]
-            outs.append(self._norm_width(op, out, tier, sticky))
+            with obs.span("lilis.exec.post"):
+                if plen > bl:
+                    out = jax.tree_util.tree_map(lambda a: a[:bl], out)
+                    ok = ok[:bl]
+                outs.append(self._norm_width(op, out, tier, sticky))
             oks.append(ok)
-        inv = np.empty(qn, np.int64)
-        inv[np.concatenate(idxs)] = np.arange(qn)
-        take = jnp.asarray(inv, jnp.int32)
-        merged = jax.tree_util.tree_map(
-            lambda *leaves: jnp.take(jnp.concatenate(leaves, axis=0),
-                                     take, axis=0), *outs)
-        ok_all = jnp.take(jnp.concatenate(oks, axis=0), take, axis=0)
-        self._pending[op.base] = (sticky, ok_all)
-        return op.post(merged)
+        with obs.span("lilis.exec.post"):
+            inv = np.empty(qn, np.int64)
+            inv[np.concatenate(idxs)] = np.arange(qn)
+            take = jnp.asarray(inv, jnp.int32)
+            merged = jax.tree_util.tree_map(
+                lambda *leaves: jnp.take(jnp.concatenate(leaves, axis=0),
+                                         take, axis=0), *outs)
+            ok_all = jnp.take(jnp.concatenate(oks, axis=0), take, axis=0)
+            self._pending[op.base] = (sticky, ok_all)
+            return op.post(merged)
 
     def _maxed_both(self, cap, cand):
         return (cap >= self.index.n_pad and
@@ -1628,19 +1715,23 @@ class Executor:
         return K.keys_to_f32(klo), K.keys_to_f32(khi)
 
     def _run_point(self, args):
-        qx = jnp.asarray(args[0], jnp.float32)
-        qy = jnp.asarray(args[1], jnp.float32)
-        qk = self._qkeys(qx, qy)
+        with obs.span("lilis.exec.prep"):
+            qx = jnp.asarray(args[0], jnp.float32)
+            qy = jnp.asarray(args[1], jnp.float32)
+            qk = self._qkeys(qx, qy)
         qs = self._use_qshard(qx.shape[0])
         fn = self._compile(self._key(("point",), qshard=qs),
                            lambda: L._PointLocal(self.index, self.cfg,
                                                  self.backend),
                            qshard=qs)
-        return self._call(fn, qx, qy, qk) > 0
+        hits = self._call(fn, qx, qy, qk)
+        with obs.span("lilis.exec.post"):
+            return hits > 0
 
     def _run_range_count(self, args):
-        rects = jnp.asarray(args[0], jnp.float32)
-        klo, khi = self._rect_keys(rects)
+        with obs.span("lilis.exec.prep"):
+            rects = jnp.asarray(args[0], jnp.float32)
+            klo, khi = self._rect_keys(rects)
         qs = self._use_qshard(rects.shape[0])
         fn = self._compile(self._key(("range_count",), qshard=qs),
                            lambda: L._RangeCountLocal(self.index,
@@ -1679,8 +1770,9 @@ class Executor:
             owidth=self._owidth_rect())
 
     def _run_range(self, spec: RangeQuery, args, strict):
-        rects = jnp.asarray(args[0], jnp.float32)
-        klo, khi = self._rect_keys(rects)
+        with obs.span("lilis.exec.prep"):
+            rects = jnp.asarray(args[0], jnp.float32)
+            klo, khi = self._rect_keys(rects)
         op = self._op_range(spec.sticky_key())
         start = None
         if spec.cap is not None:
@@ -1739,12 +1831,13 @@ class Executor:
             owidth=self._owidth_rect() if materialize else None)
 
     def _run_circle(self, spec: CircleQuery, args, strict):
-        cx = jnp.asarray(args[0], jnp.float32)
-        cy = jnp.asarray(args[1], jnp.float32)
-        r = jnp.asarray(args[2], jnp.float32)
-        rects = jnp.stack([cx - r, cy - r, cx + r, cy + r], axis=-1)
-        klo, khi = self._rect_keys(rects)
-        circ = jnp.stack([cx, cy, r], axis=-1)
+        with obs.span("lilis.exec.prep"):
+            cx = jnp.asarray(args[0], jnp.float32)
+            cy = jnp.asarray(args[1], jnp.float32)
+            r = jnp.asarray(args[2], jnp.float32)
+            rects = jnp.stack([cx - r, cy - r, cx + r, cy + r], axis=-1)
+            klo, khi = self._rect_keys(rects)
+            circ = jnp.stack([cx, cy, r], axis=-1)
         op = self._op_circle(spec.sticky_key(), spec.materialize)
         return self._adaptive(op, (rects, klo, khi, circ), strict)
 
@@ -1827,14 +1920,17 @@ class Executor:
             bucketer=self._knn_bucketer(k))
 
     def _run_knn(self, spec: Knn, args, strict):
-        qx = jnp.asarray(args[0], jnp.float32)
-        qy = jnp.asarray(args[1], jnp.float32)
+        with obs.span("lilis.exec.prep"):
+            qx = jnp.asarray(args[0], jnp.float32)
+            qy = jnp.asarray(args[1], jnp.float32)
+            if spec.mode != "exact":
+                r0 = self._knn_r0(qx, qy, spec.k)
         if spec.mode == "exact":
             qs = self._use_qshard(qx.shape[0])
             neg, vid = self._call(self._knn_exact_fn(spec.k, qshard=qs),
                                   qx, qy)
-            return -neg, vid
-        r0 = self._knn_r0(qx, qy, spec.k)
+            with obs.span("lilis.exec.post"):
+                return -neg, vid
         op = self._op_knn(spec.sticky_key(), spec.k)
         return self._adaptive(op, (qx, qy, r0), strict)
 
@@ -1873,16 +1969,17 @@ class Executor:
             feasible=self._feasible_rect(False))
 
     def _run_join(self, spec: SpatialJoin, args, strict):
-        polys = jnp.asarray(args[0], jnp.float32)
-        n_edges = jnp.asarray(args[1], jnp.int32)
-        em = L._edge_mask(polys, n_edges)
-        mbrs = jnp.concatenate([
-            jnp.min(jnp.where(em, polys, 3e38), axis=1),
-            jnp.max(jnp.where(em, polys, -3e38), axis=1)], axis=-1)
-        klo, khi = self._rect_keys(mbrs)
-        mbr_k = jnp.concatenate([mbrs, klo[:, None], khi[:, None]],
-                                axis=-1)
-        pargs = (polys, n_edges, mbr_k)
+        with obs.span("lilis.exec.prep"):
+            polys = jnp.asarray(args[0], jnp.float32)
+            n_edges = jnp.asarray(args[1], jnp.int32)
+            em = L._edge_mask(polys, n_edges)
+            mbrs = jnp.concatenate([
+                jnp.min(jnp.where(em, polys, 3e38), axis=1),
+                jnp.max(jnp.where(em, polys, -3e38), axis=1)], axis=-1)
+            klo, khi = self._rect_keys(mbrs)
+            mbr_k = jnp.concatenate([mbrs, klo[:, None], khi[:, None]],
+                                    axis=-1)
+            pargs = (polys, n_edges, mbr_k)
         if spec.mode == "full":
             qs = self._use_qshard(polys.shape[0])
             fn = self._compile(self._key(("join_full",), qshard=qs),

@@ -989,15 +989,19 @@ class _CondFusedLocal(_LocalFn):
         self.n_query_args = primary.n_query_args
 
     def __call__(self, parts, bounds, *q, axis):
-        pri = self.primary(parts, bounds, *q, axis=axis)
-        ok = self.get_ok(pri)
+        # named scopes: a device op's metadata tells the two stages apart
+        with jax.named_scope("window"):
+            pri = self.primary(parts, bounds, *q, axis=axis)
+            ok = self.get_ok(pri)
 
         def on_ok(_):
             return self.merge_ok(pri)
 
         def on_overflow(_):
-            fb = self.fallback(parts, bounds,
-                               *[q[i] for i in self.fb_args], axis=axis)
+            with jax.named_scope("fallback"):
+                fb = self.fallback(parts, bounds,
+                                   *[q[i] for i in self.fb_args],
+                                   axis=axis)
             return self.merge_fb(pri, fb)
 
         return jax.lax.cond(jnp.all(ok), on_ok, on_overflow, None), ok

@@ -65,6 +65,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core import obs
 from repro.core.executor import Executor
 from repro.core.plan import (CircleQuery, EngineConfig, InsertBatch, Knn,
                              PointQuery, QuerySpec, RangeCount,
@@ -138,12 +139,17 @@ class Ticket:
                    barrier token;
       ``batched``  the coalesced query width of the dispatch it rode
                    in (tests assert coalescing actually happened).
+
+    ``seq`` is the ticket's sequence id in its scheduler (submission
+    order); a dispatch's spans carry the ``seq`` of its first ticket.
     """
 
-    __slots__ = ("spec", "epoch", "batched", "_done", "_result", "_exc")
+    __slots__ = ("spec", "seq", "epoch", "batched", "_done", "_result",
+                 "_exc")
 
     def __init__(self, spec):
         self.spec = spec
+        self.seq = -1
         self.epoch: Optional[int] = None
         self.batched = 0
         self._done = threading.Event()
@@ -173,7 +179,7 @@ class Ticket:
 
 
 class _Request:
-    __slots__ = ("kind", "spec", "args", "qlen", "sig", "ticket")
+    __slots__ = ("kind", "spec", "args", "qlen", "sig", "ticket", "t_ns")
 
     def __init__(self, kind, spec, args, qlen, sig, ticket):
         self.kind = kind          # "read" | "write" | "maintain"
@@ -182,6 +188,7 @@ class _Request:
         self.qlen = qlen
         self.sig = sig
         self.ticket = ticket
+        self.t_ns = time.perf_counter_ns()   # submitted (queue wait)
 
 
 class SpatialScheduler:
@@ -210,6 +217,13 @@ class SpatialScheduler:
         self.write_merges = 0     # insert requests merged into a run
         self.maintain_runs = 0
         self.maintain_busy = 0    # maintain with a non-empty queue (BAD)
+        # -- time counters, ns (DESIGN.md §15) ---------------------------
+        self.queue_wait_ns = 0    # read requests: submit -> popped
+        self.queue_waits = 0      # read requests popped
+        self.dispatch_ns = 0      # inside read dispatches,
+        self.device_wait_ns = 0   # ... of which waiting on the device
+        self.maintain_ns = 0      # inside maintain() runs
+        self._batch_seq = 0       # read dispatches begun
         # -- async precompilation handoff (DESIGN.md §14) -------------
         self.width_fallbacks = 0  # dispatches at a larger warm width
         self._warm = {}           # coalescing sig -> warm pow2 widths
@@ -253,6 +267,7 @@ class SpatialScheduler:
             # the worker may have died (or close() run) while we waited
             self._check_open()
             self._q.append(req)
+            ticket.seq = self.submitted
             self.submitted += 1
             self._cv.notify_all()
         return ticket
@@ -275,6 +290,7 @@ class SpatialScheduler:
             self._check_open()
             self._q.append(_Request("maintain", None, (), 0, None,
                                     ticket))
+            ticket.seq = self.submitted
             self.submitted += 1
             self._cv.notify_all()
         return ticket
@@ -341,7 +357,11 @@ class SpatialScheduler:
             if self._q:
                 self._inflight += 1
                 self._cv.notify_all()    # free a backpressured submit
-                return self._q.popleft()
+                req = self._q.popleft()
+                if req.kind == "read":
+                    self.queue_wait_ns += time.perf_counter_ns() - req.t_ns
+                    self.queue_waits += 1
+                return req
             return None
 
     def _pop_merge(self, req: _Request, total: int):
@@ -382,7 +402,8 @@ class SpatialScheduler:
             req = self._pop()
             if req is None and groups and straggler_wait:
                 # a partial batch exists: wait briefly for stragglers
-                req = self._pop(timeout=straggler_wait)
+                with obs.span("lilis.sched.coalesce"):
+                    req = self._pop(timeout=straggler_wait)
             if req is None:
                 break
             did = True
@@ -404,7 +425,10 @@ class SpatialScheduler:
                             break
                         run.append(nxt)
                         total += nxt.qlen
-                self._dispatch_write(run, total)
+                with obs.span("lilis.sched.write", requests=len(run),
+                              queries=total, spec=req.spec.kind,
+                              ticket=req.ticket.seq):
+                    self._dispatch_write(run, total)
         flush_all()
         return did
 
@@ -429,30 +453,41 @@ class SpatialScheduler:
     def _dispatch_reads(self, reqs):
         spec = reqs[0].spec
         total = sum(r.qlen for r in reqs)
-        try:
-            width = self._pick_width(reqs, total)
-            pad = width - total
-            if len(reqs) == 1 and pad == 0:
-                args = reqs[0].args
-            else:
-                args = self._concat_pad(reqs, width)
-            out = self.ex.run(spec, *args)
-            jax.block_until_ready(out)
-        except Exception as e:           # route the failure per request
-            for r in reqs:
-                r.ticket._fail(e)
-            self._finish(len(reqs))
-            return
-        epoch = self.ex.epoch
-        lo = 0
-        for r in reqs:
-            if len(reqs) == 1 and pad == 0:
-                res = out
-            else:
-                hi = lo + r.qlen
-                res = jax.tree_util.tree_map(lambda a: a[lo:hi], out)
-            r.ticket._resolve(res, epoch, total)
-            lo += r.qlen
+        self._batch_seq += 1
+        with obs.span("lilis.sched.dispatch", (self, "dispatch_ns"),
+                      batch=self._batch_seq, requests=len(reqs),
+                      queries=total, spec=spec.kind,
+                      ticket=reqs[0].ticket.seq) as sp:
+            try:
+                with obs.span("lilis.sched.form"):
+                    width = self._pick_width(reqs, total)
+                    pad = width - total
+                    if len(reqs) == 1 and pad == 0:
+                        args = reqs[0].args
+                    else:
+                        args = self._concat_pad(reqs, width)
+                sp.set(width=width)
+                out = self.ex.run(spec, *args)
+                with obs.span("lilis.sched.device_wait",
+                              (self, "device_wait_ns")):
+                    jax.block_until_ready(out)
+            except Exception as e:       # route the failure per request
+                for r in reqs:
+                    r.ticket._fail(e)
+                self._finish(len(reqs))
+                return
+            epoch = self.ex.epoch
+            lo = 0
+            with obs.span("lilis.sched.resolve"):
+                for r in reqs:
+                    if len(reqs) == 1 and pad == 0:
+                        res = out
+                    else:
+                        hi = lo + r.qlen
+                        res = jax.tree_util.tree_map(lambda a: a[lo:hi],
+                                                     out)
+                    r.ticket._resolve(res, epoch, total)
+                    lo += r.qlen
         self.reads += total
         self.read_batches += 1
         self.max_batch = max(self.max_batch, total)
@@ -490,7 +525,9 @@ class SpatialScheduler:
                   idle: bool = False):
         with self._cv:
             qlen = len(self._q)
-        moved = self.ex.maintain()
+        with obs.span("lilis.sched.maintain", (self, "maintain_ns"),
+                      idle=int(idle)):
+            moved = self.ex.maintain()
         self.maintain_runs += 1
         if qlen:
             self.maintain_busy += 1      # should never happen on idle
@@ -520,8 +557,10 @@ class SpatialScheduler:
         straggler = self.cfg.serve_coalesce_us / 1e6
         while True:
             with self._cv:
-                while not self._q and not self._stopping:
-                    self._cv.wait(0.05)
+                if not self._q and not self._stopping:
+                    with obs.span("lilis.sched.idle"):
+                        while not self._q and not self._stopping:
+                            self._cv.wait(0.05)
                 if self._stopping and not self._q:
                     return
             self._form_and_run(straggler_wait=straggler)
@@ -592,7 +631,10 @@ class SpatialScheduler:
             "maintain_runs": self.maintain_runs,
             "maintain_busy": self.maintain_busy,
             "width_fallbacks": self.width_fallbacks,
-            "precompile_pending": len(self._pc_pending),
-            "caps": dict(self.caps),
+            "queue_wait_ns": self.queue_wait_ns,
+            "queue_waits": self.queue_waits,
+            "dispatch_ns": self.dispatch_ns,
+            "device_wait_ns": self.device_wait_ns,
+            "maintain_ns": self.maintain_ns,
             "epoch": self.ex.epoch,
         }
