@@ -53,7 +53,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from repro.core import keys as K
 from repro.core import mutate as M
 from repro.core import obs
-from repro.core import queries as Q
+from repro.core import prep as R
 from repro.core.backends import resolve_backend
 from repro.core.build import LearnedSpatialIndex
 from repro.core.plan import (CircleQuery, DeleteBatch, EngineConfig,
@@ -133,10 +133,11 @@ class _Dispatch:
     identical program; last write wins).
     """
 
-    __slots__ = ("ex", "key", "fn", "prefix", "_fns", "_names",
+    __slots__ = ("ex", "key", "fn", "prefix", "span", "_fns", "_names",
                  "_uncacheable")
 
-    def __init__(self, ex, key, fn, prefix: int):
+    def __init__(self, ex, key, fn, prefix: int,
+                 span: str = "lilis.exec.launch"):
         self.ex = ex
         self.key = key
         self.fn = fn              # traceable (not yet traced) program
@@ -144,6 +145,7 @@ class _Dispatch:
                                   # (index state, excluded from the
                                   # sig — it is keyed by shape epoch);
                                   # 0: raw-args update kernels
+        self.span = span          # the span around each launch
         self._fns = {}            # args sig -> AOT-compiled executable
         self._names = {}          # args sig -> program_name
         self._uncacheable = False
@@ -231,7 +233,7 @@ class _Dispatch:
         if fn is None:
             fn = self._realize(
                 sig, jax.tree_util.tree_map(_struct, args))
-        with obs.span("lilis.exec.launch", program=self._names[sig]):
+        with obs.span(self.span, program=self._names[sig]):
             return fn(*args)
 
     def warm(self, sig: Tuple) -> bool:
@@ -311,6 +313,7 @@ class Executor:
         self.refits = 0       # refit_partitions invocations
         self._cache = {}      # exec_key -> compiled callable
         self._sticky = {}     # sticky_key -> last-successful (cap, cand)
+        self._peak = {}       # sticky_key -> highest tier it has held
         self._initial = {}    # sticky_key -> initial-config (cap, cand)
         self._pending = {}    # sticky_key -> (tier, ok device array)
         self._escalators = {}  # sticky_key -> the op's escalate rule
@@ -321,6 +324,8 @@ class Executor:
         self.host_syncs = 0   # counted bool(jnp.all(...)) blocking reads
         self.probe_syncs = 0  # wide-batch bucketing probe readbacks
         self.dispatches = 0   # compiled-program launches
+        self.prep_launches = 0  # read prep-program launches (not
+                                # dispatches: one per read run())
         # -- compile pipeline (DESIGN.md §14) ----------------------------
         # wall-clock ms spent realizing executables (trace+lower+compile,
         # or disk load+compile on a hit), on the calling (serving)
@@ -406,11 +411,20 @@ class Executor:
         part axes, so each query-row subgroup reduces independently).
         Each shard's outputs are gathered over the query axis, and the
         pad/unpad is part of the same jitted program.
+
+        A read's prep program (base ``("prep", ...)``, core/prep.py)
+        reads no partition shard: a plain jit on a mesh too, its launch
+        spanned as ``lilis.exec.prep``.
         """
         if key in self._cache:
             return self._cache[key]
         fn = make_fn()
-        if self.mesh is None:
+        if key[2][0] == "prep":
+            out = (_Dispatch(self, key, fn, prefix=2,
+                             span="lilis.exec.prep")
+                   if self.mesh is None
+                   else jax.jit(_named(fn, program_name(key))))
+        elif self.mesh is None:
             # compiling dispatcher: per-signature AOT executables with
             # the persistent disk cache underneath (DESIGN.md §14) —
             # behaviorally a jit (bitwise-identical programs), plus
@@ -516,6 +530,9 @@ class Executor:
         old = self._sticky.get(base)
         self._sticky[base] = variant
         if old != variant:
+            self._peak[base] = max(t for t in (old, variant,
+                                               self._peak.get(base))
+                                   if t is not None)
             # a new tier starts its demotion clock from zero — clean
             # checks at the PREVIOUS tier must not count toward
             # demote_after at this one
@@ -535,11 +552,14 @@ class Executor:
         step (the seed engine's ``_jits`` bug). Sweeps both the plain
         and query-sharded wrappings (plan.exec_key layout).
 
-        FUSED programs additionally keep every ladder tier between the
-        initial and sticky tiers (O(log) of them): wide-batch bucketed
-        dispatch (DESIGN.md §13) runs light buckets at below-sticky
-        tiers on every batch, so sweeping them would recompile per
-        escalation instead of per ladder. Probe ("p") executables are
+        FUSED programs additionally keep every ladder tier from the
+        initial tier up to the one above the highest tier the base has
+        held (O(log) of them): wide-batch bucketed dispatch (DESIGN.md
+        §13) runs light buckets at below-sticky tiers on every batch,
+        and a demotion that the next overflow undoes must find the tiers
+        it left still compiled — sweeping them recompiled a whole ladder
+        of programs, at every warm batch width, inside a serving window
+        (PERF.md §6). Probe ("p") executables are
         tier-independent and only swept by shape epoch (_evict_stale).
         """
         sticky = self._sticky.get(base)
@@ -556,10 +576,11 @@ class Executor:
             if dem is not None:
                 keep_f.add(dem(*sticky))
         if esc is not None and sticky is not None and initial is not None:
+            top = esc(*max(sticky, self._peak.get(base, sticky)))
             cur = initial
             for _ in range(64):          # ladders are O(log) long
                 keep_f.add(cur)
-                if cur == sticky:
+                if cur == top:
                     break
                 nxt = esc(*cur)
                 if nxt == cur:
@@ -605,6 +626,7 @@ class Executor:
         return {"host_syncs": self.host_syncs,
                 "probe_syncs": self.probe_syncs,
                 "dispatches": self.dispatches,
+                "prep_launches": self.prep_launches,
                 "cache_size": len(self._cache),
                 "backend": self.backend.name,
                 "qshard_executables": sum(1 for k in self._cache if k[1]),
@@ -812,6 +834,8 @@ class Executor:
     def _factory_for(self, base, tag, variant):
         idx, cfg, bk = self.index, self.cfg, self.backend
         kind = base[0]
+        if kind == "prep":
+            return lambda: R.program(base, self.spec)
         if tag == "u":
             return {"insert": (lambda: M.scatter_inserts),
                     "delete": (lambda: M.apply_deletes)}.get(kind)
@@ -876,9 +900,9 @@ class Executor:
         compile. ``exercise=True`` additionally routes one zero-filled
         query batch per recorded READ family through the public ``run``
         path — outputs discarded, adaptive bookkeeping restored — to
-        absorb the per-process first-use cost of the host-side dispatch
-        prep (see ``_exercise_families``); update programs are never
-        executed. Returns {programs, compiled, skipped} counts."""
+        absorb the per-process first use of the few eager ops left on
+        the read path (see ``_exercise_families``); update programs are
+        never executed. Returns {programs, compiled, skipped} counts."""
         if not isinstance(manifest, dict) or \
                 manifest.get("version") != 1:
             return {"programs": 0, "compiled": 0, "skipped": 0}
@@ -947,12 +971,12 @@ class Executor:
         """Real ``run()`` calls per recorded read family, on zero-filled
         queries at each recorded narrow batch width, outputs discarded.
 
-        Warming the executables is not enough after a restart: the HOST
-        side of each dispatch (the eager key encodes, kNN radius
-        estimates, polygon mask builds in the per-kind preps) traces and
-        compiles dozens of tiny kernels on its first use — ~100-300 ms
-        per family in a fresh process, with the executable cache fully
-        warm — and those kernels are batch-shape-specific, so every
+        The per-kind prep is a compiled program (core/prep.py), recorded
+        in the manifest and realized by the replay itself. What this
+        still absorbs is the first use of the few eager ops left around
+        the programs — the point lookup's ``hits > 0``, kNN's negation
+        in ``post``, the fallbacks' merges — each a tiny kernel traced
+        and compiled per batch shape in a fresh process, so every
         recorded narrow width is exercised, not just one. Widths at or
         above ``tier_bucket_min`` are skipped: they dispatch through the
         data-dependent bucketed splitter, whose zero-query buckets could
@@ -1020,8 +1044,10 @@ class Executor:
 
     def _warm_targets(self, spec: QuerySpec, args) -> list:
         """(dispatcher, signature) pairs the steady path would need for
-        this (spec, args-shape). Built under the executor lock — cheap
-        (host-side key derivation + closure construction; no trace, no
+        this (spec, args-shape): the spec's prep program and the
+        programs it feeds, whose signature ``jax.eval_shape`` derives
+        from the prep (nothing runs). Built under the executor lock —
+        cheap (one abstract trace of the prep, closure construction; no
         compile); the caller warms them outside the lock."""
         out = []
 
@@ -1029,69 +1055,39 @@ class Executor:
             out.append((self._compile(key, make_fn), sig))
 
         idx, cfg, bk = self.index, self.cfg, self.backend
+        q = self._prep_inputs(spec, args)
+        base = self._prep_base(spec)
+        pargs = q
+        if base is not None:
+            make = self._factory_for(base, "x", None)
+            add(self._key(base), make, _Dispatch.sig_of(q))
+            pargs = jax.eval_shape(make(), self.parts, self.bounds, *q)
+        sig = _Dispatch.sig_of(pargs)
         if isinstance(spec, PointQuery):
-            qx = jnp.asarray(args[0], jnp.float32)
-            qy = jnp.asarray(args[1], jnp.float32)
-            pargs = (qx, qy, self._qkeys(qx, qy))
             add(self._key(("point",)),
-                lambda: L._PointLocal(idx, cfg, bk),
-                _Dispatch.sig_of(pargs))
-            return out
-        if isinstance(spec, RangeCount):
-            rects = jnp.asarray(args[0], jnp.float32)
-            pargs = (rects,) + self._rect_keys(rects)
+                lambda: L._PointLocal(idx, cfg, bk), sig)
+        elif isinstance(spec, RangeCount):
             add(self._key(("range_count",)),
-                lambda: L._RangeCountLocal(idx, cfg, bk),
-                _Dispatch.sig_of(pargs))
-            return out
-        if isinstance(spec, RangeQuery):
-            rects = jnp.asarray(args[0], jnp.float32)
-            klo, khi = self._rect_keys(rects)
+                lambda: L._RangeCountLocal(idx, cfg, bk), sig)
+        elif isinstance(spec, RangeQuery):
             self._warm_adaptive(self._op_range(spec.sticky_key()),
-                                (rects, klo, khi), add)
-            return out
-        if isinstance(spec, CircleQuery):
-            cx = jnp.asarray(args[0], jnp.float32)
-            cy = jnp.asarray(args[1], jnp.float32)
-            r = jnp.asarray(args[2], jnp.float32)
-            rects = jnp.stack([cx - r, cy - r, cx + r, cy + r], axis=-1)
-            klo, khi = self._rect_keys(rects)
-            circ = jnp.stack([cx, cy, r], axis=-1)
+                                pargs, add)
+        elif isinstance(spec, CircleQuery):
             self._warm_adaptive(
                 self._op_circle(spec.sticky_key(), spec.materialize),
-                (rects, klo, khi, circ), add)
-            return out
-        if isinstance(spec, Knn):
-            qx = jnp.asarray(args[0], jnp.float32)
-            qy = jnp.asarray(args[1], jnp.float32)
-            if spec.mode == "exact":
-                add(self._key(("knn_exact", spec.k)),
-                    lambda: L._KnnExactLocal(idx, cfg, bk, spec.k),
-                    _Dispatch.sig_of((qx, qy)))
-                return out
-            r0 = self._knn_r0(qx, qy, spec.k)
+                pargs, add)
+        elif isinstance(spec, Knn) and spec.mode == "exact":
+            add(self._key(("knn_exact", spec.k)),
+                lambda: L._KnnExactLocal(idx, cfg, bk, spec.k), sig)
+        elif isinstance(spec, Knn):
             self._warm_adaptive(self._op_knn(spec.sticky_key(), spec.k),
-                                (qx, qy, r0), add)
-            return out
-        if isinstance(spec, SpatialJoin):
-            polys = jnp.asarray(args[0], jnp.float32)
-            n_edges = jnp.asarray(args[1], jnp.int32)
-            em = L._edge_mask(polys, n_edges)
-            mbrs = jnp.concatenate([
-                jnp.min(jnp.where(em, polys, 3e38), axis=1),
-                jnp.max(jnp.where(em, polys, -3e38), axis=1)], axis=-1)
-            klo, khi = self._rect_keys(mbrs)
-            mbr_k = jnp.concatenate(
-                [mbrs, klo[:, None], khi[:, None]], axis=-1)
-            pargs = (polys, n_edges, mbr_k)
-            if spec.mode == "full":
-                add(self._key(("join_full",)),
-                    lambda: L._JoinFullLocal(idx, cfg, bk),
-                    _Dispatch.sig_of(pargs))
-                return out
+                                pargs, add)
+        elif isinstance(spec, SpatialJoin) and spec.mode == "full":
+            add(self._key(("join_full",)),
+                lambda: L._JoinFullLocal(idx, cfg, bk), sig)
+        elif isinstance(spec, SpatialJoin):
             self._warm_adaptive(self._op_join(spec.sticky_key()),
                                 pargs, add)
-            return out
         return out
 
     def _warm_adaptive(self, op: _AdaptiveOp, pargs, add) -> None:
@@ -1396,9 +1392,9 @@ class Executor:
             if isinstance(spec, Refit):
                 return self.refit()
             if isinstance(spec, PointQuery):
-                return self._run_point(args)
+                return self._run_point(spec, args)
             if isinstance(spec, RangeCount):
-                return self._run_range_count(args)
+                return self._run_range_count(spec, args)
             if isinstance(spec, RangeQuery):
                 return self._run_range(spec, args, strict)
             if isinstance(spec, CircleQuery):
@@ -1707,18 +1703,49 @@ class Executor:
 
     # -- per-kind preparation + drivers ----------------------------------
 
-    def _qkeys(self, qx, qy):
-        return K.keys_to_f32(K.make_keys(qx, qy, self.spec))
+    @staticmethod
+    def _prep_base(spec: QuerySpec) -> Optional[Tuple]:
+        """Exec-key base of the spec's prep program (core/prep.py), or
+        None where the program takes the query arrays as they are (an
+        exact kNN) or the spec is no read."""
+        if isinstance(spec, (RangeCount, RangeQuery)):
+            return ("prep", "rect")
+        if isinstance(spec, Knn):
+            return ("prep", "knn", spec.k) if spec.mode != "exact" \
+                else None
+        if isinstance(spec, (PointQuery, CircleQuery, SpatialJoin)):
+            return ("prep", spec.kind)
+        return None
 
-    def _rect_keys(self, rects):
-        klo, khi = K.rect_key_range(rects, self.spec)
-        return K.keys_to_f32(klo), K.keys_to_f32(khi)
+    def _prep_inputs(self, spec: QuerySpec, args) -> Tuple:
+        """The query arrays as device arrays of the programs' dtypes;
+        a pruned kNN adds its global-density seed radius (paper Eq. 1)
+        as a float32 scalar and the index's per-partition counts."""
+        if isinstance(spec, SpatialJoin):
+            return (jnp.asarray(args[0], jnp.float32),
+                    jnp.asarray(args[1], jnp.int32))
+        q = tuple(jnp.asarray(a, jnp.float32) for a in args)
+        if isinstance(spec, Knn) and spec.mode != "exact":
+            r0g = np.sqrt(max(spec.k, 1) / (np.pi * self.density))
+            q += (np.asarray(r0g, np.float32), self.index.count)
+        return q
 
-    def _run_point(self, args):
+    def _prepare(self, spec: QuerySpec, args) -> Tuple:
+        """The argument tuple (pargs) of a read's programs: one launch
+        of the spec's compiled prep program, counted in
+        ``prep_launches`` (not a dispatch)."""
         with obs.span("lilis.exec.prep"):
-            qx = jnp.asarray(args[0], jnp.float32)
-            qy = jnp.asarray(args[1], jnp.float32)
-            qk = self._qkeys(qx, qy)
+            q = self._prep_inputs(spec, args)
+            base = self._prep_base(spec)
+            if base is None:
+                return q
+            fn = self._compile(self._key(base),
+                               self._factory_for(base, "x", None))
+            self.prep_launches += 1
+            return fn(self.parts, self.bounds, *q)
+
+    def _run_point(self, spec, args):
+        qx, qy, qk = self._prepare(spec, args)
         qs = self._use_qshard(qx.shape[0])
         fn = self._compile(self._key(("point",), qshard=qs),
                            lambda: L._PointLocal(self.index, self.cfg,
@@ -1728,10 +1755,8 @@ class Executor:
         with obs.span("lilis.exec.post"):
             return hits > 0
 
-    def _run_range_count(self, args):
-        with obs.span("lilis.exec.prep"):
-            rects = jnp.asarray(args[0], jnp.float32)
-            klo, khi = self._rect_keys(rects)
+    def _run_range_count(self, spec, args):
+        rects, klo, khi = self._prepare(spec, args)
         qs = self._use_qshard(rects.shape[0])
         fn = self._compile(self._key(("range_count",), qshard=qs),
                            lambda: L._RangeCountLocal(self.index,
@@ -1770,16 +1795,14 @@ class Executor:
             owidth=self._owidth_rect())
 
     def _run_range(self, spec: RangeQuery, args, strict):
-        with obs.span("lilis.exec.prep"):
-            rects = jnp.asarray(args[0], jnp.float32)
-            klo, khi = self._rect_keys(rects)
+        pargs = self._prepare(spec, args)
         op = self._op_range(spec.sticky_key())
         start = None
         if spec.cap is not None:
             # user cap overrides the starting tier; cand follows sticky
             _, cand0 = self._sticky.get(op.base, op.initial)
             start = (min(spec.cap, self.index.n_pad), cand0)
-        return self._adaptive(op, (rects, klo, khi), strict, start=start)
+        return self._adaptive(op, pargs, strict, start=start)
 
     def _op_circle(self, base, materialize: bool):
         idx, cfg, bk = self.index, self.cfg, self.backend
@@ -1831,30 +1854,8 @@ class Executor:
             owidth=self._owidth_rect() if materialize else None)
 
     def _run_circle(self, spec: CircleQuery, args, strict):
-        with obs.span("lilis.exec.prep"):
-            cx = jnp.asarray(args[0], jnp.float32)
-            cy = jnp.asarray(args[1], jnp.float32)
-            r = jnp.asarray(args[2], jnp.float32)
-            rects = jnp.stack([cx - r, cy - r, cx + r, cy + r], axis=-1)
-            klo, khi = self._rect_keys(rects)
-            circ = jnp.stack([cx, cy, r], axis=-1)
         op = self._op_circle(spec.sticky_key(), spec.materialize)
-        return self._adaptive(op, (rects, klo, khi, circ), strict)
-
-    def _knn_r0(self, qx, qy, k):
-        # Paper Eq. (1): r = sqrt(k / (pi * d)) — refined with the LOCAL
-        # density of each query's nearest partition (beyond-paper: the
-        # global-density estimate needs many expansion rounds in sparse
-        # regions; the per-partition counts are free in the global index)
-        r0g = float(np.sqrt(max(k, 1) / (np.pi * self.density)))
-        bd2 = Q.box_min_dist2(qx, qy, self.bounds)
-        pid0 = jnp.argmin(bd2, axis=1)
-        b0 = self.bounds[pid0]
-        area0 = jnp.maximum((b0[:, 2] - b0[:, 0]) *
-                            (b0[:, 3] - b0[:, 1]), 1e-30)
-        d0 = jnp.maximum(self.index.count[pid0] / area0, 1e-30)
-        r0 = jnp.sqrt(k / (jnp.pi * d0)).astype(jnp.float32)
-        return jnp.maximum(r0, r0g)
+        return self._adaptive(op, self._prepare(spec, args), strict)
 
     def _knn_exact_fn(self, k, qshard: bool = False):
         return self._compile(self._key(("knn_exact", k), qshard=qshard),
@@ -1920,19 +1921,16 @@ class Executor:
             bucketer=self._knn_bucketer(k))
 
     def _run_knn(self, spec: Knn, args, strict):
-        with obs.span("lilis.exec.prep"):
-            qx = jnp.asarray(args[0], jnp.float32)
-            qy = jnp.asarray(args[1], jnp.float32)
-            if spec.mode != "exact":
-                r0 = self._knn_r0(qx, qy, spec.k)
+        pargs = self._prepare(spec, args)
         if spec.mode == "exact":
+            qx, qy = pargs
             qs = self._use_qshard(qx.shape[0])
             neg, vid = self._call(self._knn_exact_fn(spec.k, qshard=qs),
                                   qx, qy)
             with obs.span("lilis.exec.post"):
                 return -neg, vid
         op = self._op_knn(spec.sticky_key(), spec.k)
-        return self._adaptive(op, (qx, qy, r0), strict)
+        return self._adaptive(op, pargs, strict)
 
     def _op_join(self, base):
         idx, cfg, bk = self.index, self.cfg, self.backend
@@ -1969,19 +1967,9 @@ class Executor:
             feasible=self._feasible_rect(False))
 
     def _run_join(self, spec: SpatialJoin, args, strict):
-        with obs.span("lilis.exec.prep"):
-            polys = jnp.asarray(args[0], jnp.float32)
-            n_edges = jnp.asarray(args[1], jnp.int32)
-            em = L._edge_mask(polys, n_edges)
-            mbrs = jnp.concatenate([
-                jnp.min(jnp.where(em, polys, 3e38), axis=1),
-                jnp.max(jnp.where(em, polys, -3e38), axis=1)], axis=-1)
-            klo, khi = self._rect_keys(mbrs)
-            mbr_k = jnp.concatenate([mbrs, klo[:, None], khi[:, None]],
-                                    axis=-1)
-            pargs = (polys, n_edges, mbr_k)
+        pargs = self._prepare(spec, args)
         if spec.mode == "full":
-            qs = self._use_qshard(polys.shape[0])
+            qs = self._use_qshard(pargs[0].shape[0])
             fn = self._compile(self._key(("join_full",), qshard=qs),
                                lambda: L._JoinFullLocal(self.index,
                                                         self.cfg,

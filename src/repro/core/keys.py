@@ -60,8 +60,19 @@ class KeySpec:
 
 
 def quantize(coord, lo, hi, bits: int):
-    """Map float coords in [lo, hi] to integers in [0, 2^bits - 1]."""
-    scale = (1 << bits) / jnp.maximum(hi - lo, 1e-30)
+    """Map float coords in [lo, hi] to integers in [0, 2^bits - 1].
+
+    With static bounds the scale is one float32 constant divided on the
+    host, so an encode run op by op (the build, inserts) and one traced
+    into a compiled program (the read prep) agree bit for bit: XLA folds
+    a constant division on the host, and a TPU's own float32 division
+    does not always round as the host's does. A point lookup matches
+    only on an equal key."""
+    span = hi - lo
+    if isinstance(span, (int, float, np.number)):
+        scale = float(np.float32(1 << bits) / np.float32(max(span, 1e-30)))
+    else:
+        scale = (1 << bits) / jnp.maximum(span, 1e-30)
     q = jnp.floor((coord - lo) * scale)
     return jnp.clip(q, 0, (1 << bits) - 1).astype(jnp.uint32)
 

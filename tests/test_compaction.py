@@ -192,6 +192,42 @@ def test_demotion_ping_pong_backs_off(inputs):
     assert ex._sticky[base] < peak                # demotion recovers
 
 
+def test_demotions_keep_the_ladder_compiled(inputs):
+    """Demoting a tier never sweeps the fused programs of the tiers it
+    left (up to the one above the highest tier held): when the next
+    hard burst escalates back, every tier it climbs is still compiled,
+    so serving compiles nothing."""
+    from repro.core import EngineConfig, Executor, RangeQuery
+    from repro.data import spatial as ds
+    x, y, index, q = inputs
+    cfg = EngineConfig(range_cap=2, range_cand=2, demote_after=2)
+    ex = Executor(index, config=cfg)
+    base = RangeQuery().sticky_key()
+    easy = ds.random_rects(8, 1e-8, (0, 0, 1, 1), seed=5,
+                           centers=(x, y))
+    hard = ds.random_rects(8, 1e-2, (0, 0, 1, 1), seed=6,
+                           centers=(x, y))
+    ex.run(RangeQuery(), easy, strict=True)
+    ex.run(RangeQuery(), hard)
+    while ex.maintain():                          # escalate until clean
+        ex.run(RangeQuery(), hard)
+    peak = ex._sticky[base]
+    ladder = ex._ladder_tiers(ex._op_range(base), peak)
+    assert len(ladder) >= 3, ladder               # two demotions fit
+    for _ in range(20):                           # easy traffic: down
+        ex.run(RangeQuery(), easy)                # to the initial tier
+        ex.maintain()
+    assert ex._sticky[base] == ladder[0]
+    kept = {v for t, v in ex.cache_variants(base) if t == "fused"}
+    assert set(ladder) <= kept, (ladder, kept)
+    ms = ex.compile_ms_total
+    ex.run(RangeQuery(), hard)
+    while ex.maintain():                          # the burst returns
+        ex.run(RangeQuery(), hard)
+    assert ex._sticky[base] == peak
+    assert ex.compile_ms_total == ms              # nothing recompiled
+
+
 # -- helper micro-equivalence (bitwise vs the argsort forms) -------------
 
 def _ref_top_candidates(flags, c):

@@ -242,6 +242,12 @@ def test_prewarm_compiles_nothing_new_for_recorded_traffic(tmp_path,
         a.submit(spec, *args)
     man = a.manifest()
     assert man["programs"], "manifest recorded no programs"
+    preps = {tuple(p["key"][2]) for p in man["programs"]
+             if p["key"][2][0] == "prep"}
+    # every read kind's prep program is recorded with what it feeds
+    assert preps == {("prep", "point"), ("prep", "rect"),
+                     ("prep", "circle"), ("prep", "knn", 5),
+                     ("prep", "join")}, preps
     mpath = tmp_path / "prewarm.json"
     save_manifest(mpath, man)
     man = load_manifest(mpath)          # across the JSON round-trip
@@ -252,12 +258,16 @@ def test_prewarm_compiles_nothing_new_for_recorded_traffic(tmp_path,
     assert res["compiled"] > 0
     st0 = b.stats()
     assert st0["disk_cache_hits"] > 0   # prewarm loaded, not compiled
+    assert {k[2] for k, _s, _c in b.executor.compiled_programs()
+            if k[2][0] == "prep"} == preps   # the prep programs too
     for spec, *args in reqs:
         jax.block_until_ready(b.submit(spec, *args))
     st1 = b.stats()
-    # recorded traffic must ride entirely on prewarmed executables
+    # recorded traffic must ride entirely on prewarmed executables,
+    # its prep programs among them: one prep launch per read
     assert st1["cache_size"] == st0["cache_size"]
     assert st1["compile_ms_total"] == st0["compile_ms_total"]
+    assert st1["prep_launches"] - st0["prep_launches"] == len(reqs)
 
 
 def test_async_precompile_is_bitwise_neutral(built):
