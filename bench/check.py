@@ -1,9 +1,11 @@
 """The comparison that decides ``correct``.
 
 A sample of a run's reads, drawn from the seed, is judged against the
-reference (``oracle.Oracle``) on the same points. Every answer the
-configuration promises is exact, so each compared number has the
-limit 0:
+reference on the same points: ``oracle.Oracle`` over the whole set
+where the points live on the host, ``windows.Reference`` (the Oracle
+over candidate windows) where they were drawn on the device. Every
+answer the configuration promises is exact, so each compared number
+has the limit 0:
 
   wrong_answers    answers that differ from the reference's
   missing_answers  requests that raised or never completed

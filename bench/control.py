@@ -8,8 +8,10 @@ find it wrong; this prints what it reads, one JSON line per seed.
     python3 bench/control.py --workload spider-gaussian.interactive \\
         --seconds 30 --seeds 11,12,13
 
-It needs no chip (the reference runs on the host); the benchmark's own
-runs never run it.
+Points drawn on the host need no chip (the reference runs on the
+host); points drawn on the device are drawn over the cell's chips, and
+both sides are judged on the same candidate windows
+(``bench/windows.py``). The benchmark's own runs never run it.
 """
 from __future__ import annotations
 
@@ -20,23 +22,31 @@ import time
 from pathlib import Path
 
 sys.path[0] = str(Path(__file__).resolve().parents[1])
-from bench import check, deploy, gen  # noqa: E402
+from bench import check, deploy, gen, windows  # noqa: E402
 from bench import run as R  # noqa: E402
 from bench.oracle import Oracle  # noqa: E402
 
 
 def readings(cell, seed: int, seconds: float) -> dict:
+    import jax
     import ml_dtypes
     cfg = cell.cfg
-    x, y = deploy.points(cfg, int(cfg["points"]), seed, str(cell.root))
-    g = gen.Generator(cell.traffic, x, y, str(cell.root))
+    k = min(int(cell.entry["chips"]), len(jax.devices()))
+    mesh = jax.make_mesh((k,), ("data",), devices=jax.devices()[:k])
+    x, y = deploy.points(cfg, int(cfg["points"]), seed, str(cell.root),
+                         mesh)
+    g = gen.Generator(cell.traffic, *R.centres(x, y, seed), str(cell.root))
     reqs, _due = R.streams(g, cell.traffic, seed, seconds,
                            trace=False)["main"]
     keep = R.sample(reqs, cell.traffic, seed)
     answers = [(reqs[i], None, True) for i in sorted(keep)]
     t = time.perf_counter()
-    ctl = check.compare(answers, Oracle(x, y),
-                        control=Oracle(x, y, dtype=ml_dtypes.bfloat16))
+    if deploy.on_device(x):
+        orc = windows.Reference(x, y, [r for r, _, _ in answers])
+        low = orc.lowered(ml_dtypes.bfloat16)
+    else:
+        orc, low = Oracle(x, y), Oracle(x, y, dtype=ml_dtypes.bfloat16)
+    ctl = check.compare(answers, orc, control=low)
     return {"seed": seed, "control": "bfloat16 reference",
             "compared": ctl.counts["compared"],
             "wrong_answers": ctl.counts["wrong_answers"],

@@ -1,5 +1,8 @@
 """A deployment: its configuration file, its points, and its index
-built at the sizes the configuration pins.
+built at the sizes the configuration pins. The points come from
+``bench/data/<generator.kind>.py`` (on the host, or drawn on the
+device where the module can), the index from the build the
+configuration names, ``bench/builds/<build>.py``.
 
 The program sizes several static shapes from the data: the partition
 width ``n_pad`` from the largest kd-tree partition, the knot width from
@@ -21,6 +24,7 @@ import os
 import numpy as np
 
 KNOT_PAD = np.float32(3.4e38)   # the spline's padding key (core/spline.POS)
+CENTRES = 1 << 20               # query centres sampled from device points
 
 
 class PinExceeded(RuntimeError):
@@ -32,29 +36,51 @@ def load(root: str, name: str) -> dict:
         return json.load(f)
 
 
-def points(cfg: dict, n: int, seed: int, root: str = None):
-    """``n`` points of the configuration's generator for ``seed``: the
-    ``points`` of ``bench/data/<generator.kind>.py`` with its
-    ``params``."""
+def points(cfg: dict, n: int, seed: int, root: str = None, mesh=None):
+    """``n`` points of the configuration's generator for ``seed``, from
+    ``bench/data/<generator.kind>.py`` with its ``params``: drawn on the
+    device and sharded over ``mesh`` (default: the first device) where
+    the module has ``device_points``, else its host ``points``."""
     from bench.gen import module
     g = cfg["generator"]
-    return module("data", g["kind"], root).points(n, seed,
-                                                  **g.get("params", {}))
+    mod = module("data", g["kind"], root)
+    if not hasattr(mod, "device_points"):
+        return mod.points(n, seed, **g.get("params", {}))
+    if mesh is None:
+        import jax
+        mesh = jax.make_mesh((1,), ("data",), devices=jax.devices()[:1])
+    return mod.device_points(n, seed, mesh, **g.get("params", {}))
 
 
-def build(cfg: dict, x, y, seed: int):
-    """(index, partitioner) at the pinned static shapes."""
+def on_device(x) -> bool:
+    """Whether the points live on the device."""
+    return not isinstance(x, np.ndarray)
+
+
+def centres(x, y, seed: int):
+    """The points the traffic centres its queries on, on the host: the
+    points themselves where they live there, else a uniform sample of
+    ``CENTRES`` of them drawn from ``seed`` (all of them where there
+    are no more)."""
+    if not on_device(x):
+        return x, y
+    from bench import windows
+    n = int(x.shape[0])
+    idx = (np.arange(n) if n <= CENTRES
+           else np.random.default_rng(seed).integers(0, n, CENTRES))
+    return windows.take(x, y, idx)
+
+
+def build(cfg: dict, x, y, seed: int, mesh=None, root: str = None):
+    """(index, partitioner) from the configuration's build,
+    ``bench/builds/<build>.py``, held to the pinned static shapes."""
     import jax
     import jax.numpy as jnp
-    from repro.core import build_index, fit
-    from repro.core.keys import KeySpec
+    from bench.gen import module
 
     pin = cfg["pinned"]
-    part = fit(cfg["partitioner"], x, y, int(pin["partitions"]), seed=seed)
-    index = build_index(x, y, part,
-                        key_spec=KeySpec(bounds=tuple(pin["key_bounds"])),
-                        n_pad=int(pin["n_pad"]),
-                        radix_bits=int(pin["radix_bits"]))
+    index, part = module("builds", cfg["build"], root).build(
+        cfg, x, y, seed, mesh)
     biggest = int(jnp.max(index.count))
     if biggest > index.n_pad:
         raise PinExceeded(f"largest partition holds {biggest} points, "

@@ -13,6 +13,9 @@ from __future__ import annotations
 
 import numpy as np
 
+KNN_W0 = 1e-3       # the first half-width of the kNN window
+KNN_WMAX = 4.0      # past it, the kNN window takes every point
+
 
 def coord_keys(x, y):
     """Exact (x, y) identity as one uint64 per point."""
@@ -78,14 +81,14 @@ class Oracle:
         stored float32 coordinates, over a square window grown until
         its k-th distance lies inside the window's inscribed circle."""
         qx, qy = (np.float32(v) for v in self.q([qx, qy]))
-        w = 1e-3
+        w = KNN_W0
         while True:
             sx, sy, _ = self._slab(qx - w, qx + w)
             m = np.abs(sy - qy) <= w
-            if m.sum() >= k or w > 4.0:
+            if m.sum() >= k or w > KNN_WMAX:
                 d2 = np.sort(self._d2(sx[m], sy[m], qx, qy))[:k]
                 if len(d2) == k and (np.sqrt(d2[-1]) < 0.999 * w
-                                     or w > 4.0):
+                                     or w > KNN_WMAX):
                     return d2
             w *= 2.0
 

@@ -13,8 +13,13 @@ run:
 
   1. fails (exit 3, no result) when JAX finds no TPU or fewer chips
      than the cell asks for, or a device kind ``bench/peaks.py`` lacks;
-  2. generates the deployment's points from ``--seed`` on the host;
-  3. fits and builds the index at the configuration's pinned shapes;
+  2. generates the deployment's points from ``--seed``: on the host, or
+     on the device where the configuration's generator draws there
+     (sharded over the cell's chips; the traffic then centres its
+     queries on a seeded sample of them);
+  3. builds the index with the configuration's build
+     (``bench/builds/<build>.py``) at its pinned shapes; a cell on more
+     than one chip serves it over a ``("data",)`` mesh of its chips;
   4. warms up the cell's shapes: the tiers its mix settles, every
      power-of-two width the scheduler can coalesce its requests to, the
      scheduler's concatenation shapes, then ``warm_seconds`` of the
@@ -25,7 +30,10 @@ run:
      as ``bench/drive.py``'s client;
   6. with ``--trace 1``, profiles a few seconds of traffic of its own;
   7. frees the program's state and compares a seeded sample of the
-     answers with the plain reference (``bench/check.py``);
+     answers with the plain reference (``bench/check.py``): the
+     whole-set one for points drawn on the host, else the one over
+     candidate windows of points drawn again on the device
+     (``bench/windows.py``);
   8. prints the compared numbers beside their limits as the last lines
      of stderr, and one JSON result line as the last line of stdout.
 
@@ -59,7 +67,7 @@ if sys.path and Path(sys.path[0]).resolve() == Path(__file__).parent:
 elif str(ROOT) not in sys.path:
     sys.path.insert(0, str(ROOT))
 
-from bench import check, deploy, drive, gen, peaks  # noqa: E402
+from bench import check, deploy, drive, gen, peaks, windows  # noqa: E402
 from bench import trace as tracing  # noqa: E402
 
 
@@ -133,6 +141,12 @@ def devices(chips: int, require_tpu: bool):
     return devs
 
 
+def centres(x, y, seed: int):
+    """The points a run's traffic centres its queries on
+    (``deploy.centres``), from the run's seed."""
+    return deploy.centres(x, y, seed_of(seed, 12))
+
+
 def _bucket(n: int) -> int:
     return 1 if n <= 1 else 1 << (n - 1).bit_length()
 
@@ -171,29 +185,45 @@ class Setup:
     """Everything a run builds before its window."""
 
     def __init__(self, cell: Cell, seed: int, seconds: float,
-                 trace: bool, cache: bool = True, engine: dict = None):
+                 trace: bool, devs, cache: bool = True,
+                 engine: dict = None):
         import jax
         from repro.core import EngineConfig
         from repro.serve.spatial import SpatialServeSession
 
         cfg, traffic = cell.cfg, cell.traffic
         self.cell, self.seed, self.seconds = cell, seed, seconds
+        chips = int(cell.entry["chips"])
+        devs = devs[:chips]
+        # the serve mesh (none on one chip) and the mesh the points are
+        # drawn over
+        self.mesh = (jax.make_mesh((chips,), ("data",), devices=devs)
+                     if chips > 1 else None)
+        self.data_mesh = self.mesh or jax.make_mesh((1,), ("data",),
+                                                    devices=devs)
         t = time.perf_counter()
-        self.x, self.y = deploy.points(cfg, int(cfg["points"]), seed,
-                                       str(cell.root))
-        log(f"data: {cfg['generator']['kind']} n={len(self.x)} in "
+        x, y = deploy.points(cfg, int(cfg["points"]), seed, str(cell.root),
+                             self.data_mesh)
+        self.on_device = deploy.on_device(x)
+        log(f"data: {cfg['generator']['kind']} n={x.shape[0]} "
+            f"{'on the device' if self.on_device else 'on the host'} in "
             f"{time.perf_counter() - t:.3f} s")
         t = time.perf_counter()
-        index, _ = deploy.build(cfg, self.x, self.y, seed)
+        index, _ = deploy.build(cfg, x, y, seed, self.mesh, str(cell.root))
         self.shapes = deploy.shapes(index)
-        log(f"index built in {time.perf_counter() - t:.3f} s: "
-            f"{self.shapes}")
+        log(f"index built by {cfg['build']!r} in "
+            f"{time.perf_counter() - t:.3f} s: {self.shapes}")
+        # the points drawn on the device are freed here; the check draws
+        # them again
+        self.x, self.y = centres(x, y, seed)
+        del x, y
         root = None
         if cache:
             from repro.core.compile_cache import default_cache_root
             root = default_cache_root()
-        self.session = SpatialServeSession(index, config=EngineConfig(
-            compile_cache_dir=root, **(engine or {})))
+        self.session = SpatialServeSession(
+            index, mesh=self.mesh, part_axis="data",
+            config=EngineConfig(compile_cache_dir=root, **(engine or {})))
         del index
         self.ex = self.session.executor
         log(f"backend {self.ex.backend.name} "
@@ -351,6 +381,13 @@ def programs(ex) -> set:
             for k, sig, _ in ex.compiled_programs()}
 
 
+def memory_peaks(devs) -> list:
+    """Each device's peak bytes in use (0 where the backend keeps no
+    count)."""
+    return [int((d.memory_stats() or {}).get("peak_bytes_in_use", 0))
+            for d in devs]
+
+
 def delta(a: dict, b: dict) -> dict:
     return {layer: {k: b[layer][k] - a[layer][k] for k in a[layer]}
             for layer in a}
@@ -421,8 +458,9 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool,
     before the window."""
     import jax
     import numpy as np
-    devs = devices(int(cell.entry["chips"]), require_tpu)
-    st = Setup(cell, seed, seconds, trace, cache=cache, engine=engine)
+    chips = int(cell.entry["chips"])
+    devs = devices(chips, require_tpu)
+    st = Setup(cell, seed, seconds, trace, devs, cache=cache, engine=engine)
     if fault is not None:
         fault(st.session)
     setup_s = time.perf_counter() - T0
@@ -439,8 +477,7 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool,
     if red is not None:
         log(f"trace: busy {red['busy_s']:.6f} s of {red['window_s']:.6f} "
             f"s, kernels {red['kernel_s']:.6f} s, {red['gaps']} gaps")
-    stats = devs[0].memory_stats() or {}
-    peak = int(stats.get("peak_bytes_in_use", 0))
+    mem = memory_peaks(devs[:chips])
     st.sched.close()
     # free the program's state before the reference runs
     answers = lg.answers()
@@ -448,12 +485,19 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool,
     attempted = lg.issued
     failed = sum(1 for i in range(lg.issued)
                  if lg.error[i] is not None or np.isnan(lg.done[i]))
-    x, y = st.x, st.y
+    x, y, mesh, drawn = st.x, st.y, st.data_mesh, st.on_device
     del st, lg
     gc.collect()
     jax.clear_caches()
     t = time.perf_counter()
-    rp = check.compare(answers, check.Oracle(x, y))
+    if drawn:
+        cfg = cell.cfg
+        x, y = deploy.points(cfg, int(cfg["points"]), seed, str(cell.root),
+                             mesh)
+        orc = windows.Reference(x, y, [req for req, _, _ in answers])
+    else:
+        orc = check.Oracle(x, y)
+    rp = check.compare(answers, orc)
     log(f"reference: {rp.counts['compared']} answers compared in "
         f"{time.perf_counter() - t:.3f} s; wrong by family {rp.wrong}")
     checks = {k: {"value": rp.counts[k], "limit": lim}
@@ -473,7 +517,8 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool,
                 metrics[m["name"]] = {"value": e2e[m["name"]],
                                       "unit": m["unit"]}
     device = {"platform": devs[0].platform, "kind": devs[0].device_kind,
-              "count": len(devs), "memory_peak_bytes": peak}
+              "count": len(devs), "memory_peak_bytes": max(mem),
+              "memory_peak_bytes_per_device": mem}
     out = {"correct": bool(correct), "attempted": int(attempted),
            "failed": int(failed), "metrics": metrics, "device": device}
     if red is not None:

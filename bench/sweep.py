@@ -36,11 +36,11 @@ def main():
     os.environ["JAX_COMPILATION_CACHE_DIR"] = str(R.CACHE)
     sys.path.insert(0, str(R.SRC))
     cell = R.Cell(args.workload)
-    R.devices(int(cell.entry["chips"]), True)
+    devs = R.devices(int(cell.entry["chips"]), True)
     rates = [float(r) for r in args.rates.split(",")]
     cell.traffic["rate"] = rates[0]      # set-up's warm traffic
     R.drive.LATE_S = args.late
-    st = R.Setup(cell, args.seed, args.seconds, False)
+    st = R.Setup(cell, args.seed, args.seconds, False, devs)
     for k, rate in enumerate(rates):
         cell.traffic["rate"] = rate
         st.streams["main"] = st.gen.stream(args.seconds,
